@@ -1,12 +1,13 @@
 """Fig. 6 reproduction — simulation fidelity: predicted vs ACTUAL speedups.
 
 The paper compares APEX-predicted speedups against vLLM/SGLang runs on
-GPUs (mean relative error 10.7%).  Our hardware is this host's CPU, so the
-loop closes the same way at reduced scale: the simulator (profiling tables
-MEASURED on this CPU, core/profiles.MeasuredBackend) predicts serving
-outcomes for configuration variants, and the REAL JAX engine
-(serving/engine.py) runs them.  Variants exercised: max-batch-size caps —
-the serving-dynamics knob (paper §4.6) measurable on one device.
+GPUs (mean relative error 10.7%).  Here the loop closes the same way at
+reduced scale on whatever device jax reports first: the simulator
+(profiling tables MEASURED on that device in the model's dtype,
+core/profiles.MeasuredBackend) predicts serving outcomes for configuration
+variants, and the REAL JAX engine (serving/engine.py) runs them.  Variants
+exercised: max-batch-size caps — the serving-dynamics knob (paper §4.6)
+measurable on one device.
 
 Reported: per-variant predicted vs actual slowdown relative to the best
 variant + mean relative error.
@@ -18,8 +19,7 @@ import jax
 import numpy as np
 
 from repro import configs as C
-from repro.core import (ApexSearch, BatchingPolicy, cpu_local,
-                        MeasuredBackend, Request)
+from repro.core import ApexSearch, BatchingPolicy, MeasuredBackend, Request
 from repro.core.planner import heuristic_scheme
 from repro.data.requests import make_serving_requests
 from repro.models import transformer as T
@@ -34,7 +34,6 @@ def run(arch: str = "qwen2_0_5b", n_requests: int = 6, gen_len: int = 8,
         ctx: int = 12, quick: bool = False):
     cfg = C.get_reduced(arch)
     model = cfg.to_ir()
-    cluster = cpu_local()
     caps = CAPS[:2] if quick else CAPS
 
     # --- real engine runs ---
@@ -50,14 +49,15 @@ def run(arch: str = "qwen2_0_5b", n_requests: int = 6, gen_len: int = 8,
         rep = eng.run([dict(r) for r in reqs], time_scale=0.0)
         actual[cap] = rep.total_time
 
-    # --- simulator predictions with CPU-measured op tables ---
-    backend = MeasuredBackend(cluster)
+    # --- simulator predictions with op tables measured on this device ---
+    backend = MeasuredBackend()
+    cluster = backend.cluster
     search = ApexSearch(model, cluster, backend=backend)
     search.store.x_max = 4096
     sim_reqs = [Request(rid=r["rid"], arrival=0.0,
                         context_len=len(r["prompt"]), gen_len=r["gen_len"])
                 for r in reqs]
-    scheme = heuristic_scheme(model, 1, cluster)
+    scheme = heuristic_scheme(model, 1, cluster, quant="bf16")
     predicted = {}
     for cap in caps:
         rep = search.evaluate(scheme, sim_reqs,
@@ -87,4 +87,6 @@ def run(arch: str = "qwen2_0_5b", n_requests: int = 6, gen_len: int = 8,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

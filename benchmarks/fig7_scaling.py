@@ -24,24 +24,24 @@ _MEASURE = r"""
 import os, sys, json, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(n)d"
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs as C
+from repro.core import generate_schemes
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
-from repro.parallel.sharding import param_pspecs, cache_pspecs
+from repro.parallel.plan_sharding import plan_to_shardings
 
+enable_compile_cache()
 cfg = C.get_reduced("internlm2_1_8b")
-mesh = jax.make_mesh((1, %(n)d), ("data", "model"))
+scheme = next(s for s in generate_schemes(cfg.to_ir(), %(n)d)
+              if s.model_dp == 1 and s.pp_stages == 1)
 params = T.init_params(jax.random.PRNGKey(0), cfg)
 cache = T.init_cache(cfg, 4, 128)
-sh = lambda t, specs: jax.device_put(t, jax.tree.map(
-    lambda s: NamedSharding(mesh, s), specs,
-    is_leaf=lambda s: isinstance(s, P)))
-from repro.launch.mesh import mesh_context
-with mesh_context(mesh):
-    ps = sh(params, param_pspecs(params, cfg, mesh))
-    cs = sh(cache, cache_pspecs(cache, cfg, mesh))
-    toks = jnp.ones((4, 1), jnp.int32)
-    step = jax.jit(lambda p, t, c: T.decode_step(p, cfg, t, c))
+mat = plan_to_shardings(scheme, cfg, params)
+ps = jax.device_put(params, mat.param_shardings())
+cs = jax.device_put(cache, mat.cache_shardings(cache, cfg))
+toks = jnp.ones((4, 1), jnp.int32)
+step = jax.jit(lambda p, t, c: T.decode_step(p, cfg, t, c))
+with jax.set_mesh(mat.mesh):
     logits, cs2 = step(ps, toks, cs)
     jax.block_until_ready(logits)
     t0 = time.perf_counter()

@@ -1,15 +1,42 @@
 """Benchmark driver: one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows.  ``--full`` runs the
-complete settings (the EXPERIMENTS.md numbers); default is the quick
-variant for CI-style validation.
+complete settings; default is the quick variant for CI-style validation.
+
+Each entry runs in a Python process of its own and this parent never
+imports jax: a chip belongs to one process at a time, and an entry that
+starts device-using children of its own (fig7) needs a parent that holds
+none.
+
+    PYTHONPATH=src python -m benchmarks.run --only disagg,table2
 """
 
 from __future__ import annotations
 
 import argparse
+import os
+import subprocess
 import sys
-import traceback
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+BENCHES = {
+    "disagg": "disagg_frontier",
+    "table2": "table2_plan_search",
+    "table3": "table3_clusters",
+    "table4": "table4_energy",
+    "table5": "table5_extensibility",
+    "fig6": "fig6_fidelity",
+    "fig7": "fig7_scaling",
+    "fig8": "fig8_scaling",
+    "fig9": "fig9_slo",
+    "roofline": "roofline",
+}
+
+_CHILD = ("from repro.launch.compile_cache import enable_compile_cache\n"
+          "enable_compile_cache()\n"
+          "from benchmarks import {module}\n"
+          "{module}.run(quick={quick})\n")
 
 
 def main() -> None:
@@ -19,35 +46,21 @@ def main() -> None:
                     help="comma-separated subset (table2,fig6,...)")
     args, _ = ap.parse_known_args()
     quick = not args.full
-
-    from . import (disagg_frontier, fig6_fidelity, fig7_scaling,
-                   fig8_scaling, fig9_slo, roofline, table2_plan_search,
-                   table3_clusters, table4_energy, table5_extensibility)
-
-    benches = {
-        "disagg": lambda: disagg_frontier.run(quick=quick),
-        "table2": lambda: table2_plan_search.run(quick=quick),
-        "table3": lambda: table3_clusters.run(quick=quick),
-        "table4": lambda: table4_energy.run(quick=quick),
-        "table5": lambda: table5_extensibility.run(quick=quick),
-        "fig6": lambda: fig6_fidelity.run(quick=quick),
-        "fig7": lambda: fig7_scaling.run(quick=quick),
-        "fig8": lambda: fig8_scaling.run(quick=quick),
-        "fig9": lambda: fig9_slo.run(quick=quick),
-        "roofline": lambda: roofline.run(quick=quick),
-    }
     only = set(args.only.split(",")) if args.only else None
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(ROOT, "src"), ROOT]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     failures = 0
-    print("name,us_per_call,derived")
-    for name, fn in benches.items():
+    print("name,us_per_call,derived", flush=True)
+    for name, module in BENCHES.items():
         if only and name not in only:
             continue
-        try:
-            fn()
-        except Exception:  # noqa: BLE001 — report and continue
+        code = _CHILD.format(module=module, quick=quick)
+        if subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                          env=env).returncode:
             failures += 1
             print(f"{name},0,FAILED", flush=True)
-            traceback.print_exc()
     if failures:
         sys.exit(1)
 

@@ -8,6 +8,7 @@ batching, greedy admission, preemption and all.
 
 import argparse
 
+from repro import configs as C
 from repro.launch.serve import serve
 
 if __name__ == "__main__":
@@ -15,5 +16,7 @@ if __name__ == "__main__":
     ap.add_argument("--arch", default="mixtral-8x7b")
     ap.add_argument("--trace", default="chat")
     ap.add_argument("--requests", type=int, default=8)
+    ap.add_argument("--cluster", default="h100x8")
     args = ap.parse_args()
-    serve(args.arch, args.trace, args.requests)
+    serve(args.arch, C.get_reduced(args.arch), trace=args.trace,
+          requests=args.requests, cluster=args.cluster)

@@ -315,8 +315,18 @@ CLUSTER_PRESETS = {
     "h100x8": h100_node,
     "h100x16-2node": h100_multinode,
     "h200x8": h200_node,
+    # one v5e chip, and one four-chip host (a 2x2 slice: rings of 2)
+    "tpu-v5e-1": lambda: tpu_v5e_pod(chips=1, ring_group=1),
+    "tpu-v5e-4": lambda: tpu_v5e_pod(chips=4, ring_group=2),
     "tpu-v5e-256": tpu_v5e_pod,
     "tpu-v5e-512-2pod": tpu_v5e_multipod,
+}
+
+# jax ``Device.device_kind`` -> the one-device cluster preset describing
+# it.  A device kind that is not listed is an error, never a default.
+DEVICE_KIND_CLUSTERS = {
+    "TPU v5 lite": "tpu-v5e-1",
+    "cpu": "cpu-local",
 }
 
 
@@ -325,3 +335,12 @@ def get_cluster(name: str) -> Cluster:
     if name not in CLUSTER_PRESETS:
         raise KeyError(f"unknown cluster {name!r}; known: {sorted(CLUSTER_PRESETS)}")
     return CLUSTER_PRESETS[name]()
+
+
+def cluster_for_device_kind(kind: str) -> Cluster:
+    """The one-device cluster of a jax ``device_kind`` (its DeviceSpec
+    carries the peaks and power the profiler's timings are paired with)."""
+    if kind not in DEVICE_KIND_CLUSTERS:
+        raise KeyError(f"no DeviceSpec for device kind {kind!r}; known: "
+                       f"{sorted(DEVICE_KIND_CLUSTERS)}")
+    return get_cluster(DEVICE_KIND_CLUSTERS[kind])

@@ -11,10 +11,10 @@ for the profiler:
   * ``AnalyticBackend`` — closed-form roofline-with-efficiency-curve model
     of the target device (H100/H200/TPU v5e presets).  This is what the
     GPU-hours profiling job would have produced, up to calibration.
-  * ``MeasuredBackend`` — actually executes the operation in JAX on THIS
-    machine's CPU and times it.  Used by the fidelity experiments (Fig. 6/7
-    reproduction): the simulator predicts, the real JAX serving engine runs,
-    both on the same silicon.
+  * ``MeasuredBackend`` — actually executes the operation in JAX on this
+    process's first device, in the op's compute dtype, and times it.  Used
+    by the fidelity experiments (Fig. 6/7 reproduction): the simulator
+    predicts, the real JAX serving engine runs, both on the same silicon.
 
 Either way the simulator only ever sees a ``ProfileStore``: sparse grids of
 (x, time, energy) points per (op, axes) key, linear interpolation between
@@ -152,65 +152,79 @@ def _op_work(op: str, axes: tuple, x: float) -> Tuple[float, float, str]:
 
 
 class MeasuredBackend(ProfileBackend):
-    """Times the ACTUAL operation in JAX on this host (the fidelity
-    experiments' profiler: the simulator predicts the engine running on
-    the same silicon, closing the paper's Fig. 6/7 loop on CPU).
+    """Times the ACTUAL operation in JAX on this process's first device
+    (the fidelity experiments' profiler: the simulator predicts the engine
+    running on the same silicon, closing the paper's Fig. 6/7 loop).
+
+    ``cluster`` is the one-device cluster of that device's ``device_kind``
+    (``cluster_for_device_kind``); an unknown kind raises.  Ops run in
+    their compute dtype (``axes[-1]``); a dtype without a jax counterpart
+    here raises rather than being timed as another.
 
     Pair with ``ProfileStore(x_max=...)`` so the grid stays measurable;
     beyond the grid the store extrapolates linearly — the same mechanism
     the paper uses between profiled points.
     """
 
-    def __init__(self, cluster: Optional[Cluster] = None, repeats: int = 3):
+    DTYPES = {"fp32": "float32", "bf16": "bfloat16", "fp16": "float16"}
+
+    def __init__(self, repeats: int = 3):
         import jax
         import jax.numpy as jnp
-        from .cluster import cpu_local
+        from .cluster import cluster_for_device_kind
         from .energy import PowerModel
         self._jax, self._jnp = jax, jnp
-        self.cluster = cluster or cpu_local()
+        self.cluster = cluster_for_device_kind(jax.devices()[0].device_kind)
         self.repeats = repeats
         self.power = PowerModel(self.cluster.device)
+
+    def _dtype(self, name: str):
+        if name not in self.DTYPES:
+            raise KeyError(f"MeasuredBackend cannot time compute dtype "
+                           f"{name!r}; known: {sorted(self.DTYPES)}")
+        return self._jnp.dtype(self.DTYPES[name])
 
     def _build(self, op: str, axes: tuple, x: float):
         jax, jnp = self._jax, self._jnp
         key = jax.random.PRNGKey(0)
         n_x = max(1, int(x))
+        dt = self._dtype(axes[-1])
         if op == "gemm":
             n, k, _ = axes
-            a = jax.random.normal(key, (n_x, k), jnp.float32)
-            b = jax.random.normal(key, (k, n), jnp.float32)
+            a = jax.random.normal(key, (n_x, k), dt)
+            b = jax.random.normal(key, (k, n), dt)
             return jax.jit(lambda a, b: a @ b), (a, b)
         if op == "attn_prefill":
             heads, head_dim, _ = axes
             s = max(2, int(math.sqrt(n_x)))
-            q = jax.random.normal(key, (1, s, heads, head_dim), jnp.float32)
+            q = jax.random.normal(key, (1, s, heads, head_dim), dt)
             def f(q):
-                w = jnp.einsum("bqhd,bkhd->bhqk", q, q)
-                p = jax.nn.softmax(w, axis=-1)
+                w = jnp.einsum("bqhd,bkhd->bhqk", q, q,
+                               preferred_element_type=jnp.float32)
+                p = jax.nn.softmax(w, axis=-1).astype(dt)
                 return jnp.einsum("bhqk,bkhd->bqhd", p, q)
             return jax.jit(f), (q,)
         if op == "attn_decode":
             kv_heads, head_dim, _ = axes
-            kv = jax.random.normal(key, (1, n_x, kv_heads, head_dim),
-                                   jnp.float32)
-            q = jax.random.normal(key, (1, 1, kv_heads, head_dim),
-                                  jnp.float32)
+            kv = jax.random.normal(key, (1, n_x, kv_heads, head_dim), dt)
+            q = jax.random.normal(key, (1, 1, kv_heads, head_dim), dt)
             def f(q, kv):
-                w = jnp.einsum("bqhd,bkhd->bhqk", q, kv)
-                p = jax.nn.softmax(w, axis=-1)
+                w = jnp.einsum("bqhd,bkhd->bhqk", q, kv,
+                               preferred_element_type=jnp.float32)
+                p = jax.nn.softmax(w, axis=-1).astype(dt)
                 return jnp.einsum("bhqk,bkhd->bqhd", p, kv)
             return jax.jit(f), (q, kv)
         if op == "ssd_scan":
             d_inner, d_state, _ = axes
             h = max(1, d_inner // 64)
             from repro.kernels.ssd_scan.ref import ssd_scan_ref
-            xx = jax.random.normal(key, (1, n_x, h, 64), jnp.float32)
-            dt = jnp.ones((1, n_x, h), jnp.float32)
+            xx = jax.random.normal(key, (1, n_x, h, 64), dt)
+            dtt = jnp.ones((1, n_x, h), jnp.float32)
             al = jnp.zeros((h,), jnp.float32)
-            b = jax.random.normal(key, (1, n_x, d_state), jnp.float32)
+            b = jax.random.normal(key, (1, n_x, d_state), dt)
             return (jax.jit(lambda x, d, a, bb:
                             ssd_scan_ref(x, d, a, bb, bb)),
-                    (xx, dt, al, b))
+                    (xx, dtt, al, b))
         raise KeyError(op)
 
     def measure(self, op: str, axes: tuple, x: float) -> Tuple[float, float]:

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
+import sys
 import time as _time
 from typing import Callable, List, Optional, Sequence, Tuple
 
@@ -145,6 +146,17 @@ def _serial_map(fn: Callable[[int], object], n: int,
     return out
 
 
+def _holds_accelerator() -> bool:
+    """True when this process has already brought up a non-CPU jax backend
+    (and so holds the chip).  Never initializes a backend itself."""
+    if "jax" not in sys.modules:
+        return False
+    import jax
+    from jax._src import xla_bridge
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() != "cpu")
+
+
 def fork_map(fn: Callable[[int], object], n: int, jobs: int,
              progress: Optional[Callable[[int], None]] = None,
              label: Optional[Callable[[int], str]] = None) -> list:
@@ -160,9 +172,18 @@ def fork_map(fn: Callable[[int], object], n: int, jobs: int,
     A crash inside ``fn(i)`` — serial or forked — raises
     ``PlanEvaluationError`` naming the failing index (and its
     ``label(i)``, when given), never a bare worker traceback.
+
+    Forking is refused (``RuntimeError``) once this process has brought up
+    an accelerator backend: the children would inherit its hold on the
+    chip.  Run the search before touching the device, or with jobs=1.
     """
     if jobs <= 1 or n <= 1:
         return _serial_map(fn, n, progress, label)
+    if _holds_accelerator():
+        raise RuntimeError(
+            "fork_map(jobs>1) after this process brought up an accelerator "
+            "backend: forked workers would inherit the chip; use jobs=1 or "
+            "search before the first device use")
     import multiprocessing as mp
     if "fork" not in mp.get_all_start_methods():
         import warnings

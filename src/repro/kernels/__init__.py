@@ -2,7 +2,9 @@
 
 Each kernel package ships three files:
   * ``<name>.py`` — the pl.pallas_call kernel with explicit BlockSpec VMEM
-    tiling (TPU is the TARGET; validated with interpret=True on CPU),
+    tiling (TPU is the TARGET; validated with interpret=True on CPU,
+    compiled for a described v5e by tests/test_tpu_compile.py and run
+    against ``ref.py`` on the chip by chip_smoke.py),
   * ``ops.py``    — the jit'd public wrapper that dispatches kernel vs.
     pure-jnp fallback,
   * ``ref.py``    — the pure-jnp oracle the tests assert_allclose against.

@@ -12,7 +12,10 @@ the KV cache through VMEM exactly once at full HBM bandwidth.  Tiling:
     x (D x block_kv) matmul — enough arithmetic intensity to keep the MXU
     from starving while staying bandwidth-limited (this is the TPU
     adaptation; a CUDA kernel would instead parallelize across warps).
-  * per-sequence valid length masks ring/linear caches uniformly.
+  * per-sequence valid length masks ring/linear caches uniformly; the
+    length vector sits whole in SMEM.
+  * the wrapper lays K/V out as (B, Hkv, S, D) so every VMEM block ends in
+    (rows, D), the shape the TPU's (8, 128) tiling accepts.
 """
 
 from __future__ import annotations
@@ -40,7 +43,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    n_valid = len_ref[0]
+    n_valid = len_ref[pl.program_id(0)]
     k_lo = ki * block_kv
 
     @pl.when(k_lo < n_valid)
@@ -54,13 +57,13 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
             preferred_element_type=jnp.float32) * scale   # (group, bk)
         slot = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, block_kv), 1)
         s = jnp.where(slot < n_valid, s, NEG_INF)
-        m_prev = m_scr[...]                               # (group,)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                               # (group, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
         m_scr[...] = m_new
-        acc_scr[...] = (acc_scr[...] * corr[:, None]
+        acc_scr[...] = (acc_scr[...] * corr
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -68,7 +71,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ki == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[...] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -84,9 +87,12 @@ def decode_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     group = Hq // Hkv
     bk = min(block_kv, Smax)
     S_p = -(-Smax // bk) * bk
+    # (B, Hkv, S, D): each K/V block is a (bk, D) panel
+    k = k.transpose(0, 2, 1, 3)
+    v = v.transpose(0, 2, 1, 3)
     if S_p != Smax:
-        k = jnp.pad(k, ((0, 0), (0, S_p - Smax), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, S_p - Smax), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, S_p - Smax), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, S_p - Smax), (0, 0)))
     # group query heads by kv head: (B, Hkv, group, D)
     qg = q.reshape(B, Hkv, group, D)
 
@@ -96,21 +102,20 @@ def decode_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((None, None, group, D),
                          lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((None, bk, None, D),
-                         lambda b, h, ki: (b, ki, h, 0)),
-            pl.BlockSpec((None, bk, None, Dv),
-                         lambda b, h, ki: (b, ki, h, 0)),
+            pl.BlockSpec((None, None, bk, D),
+                         lambda b, h, ki: (b, h, ki, 0)),
+            pl.BlockSpec((None, None, bk, Dv),
+                         lambda b, h, ki: (b, h, ki, 0)),
         ],
         out_specs=pl.BlockSpec((None, None, group, Dv),
                                lambda b, h, ki: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, Dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
+            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, Dv), jnp.float32),
         ],
         interpret=interpret,

@@ -10,6 +10,9 @@ TPU-native tiling, not a CUDA port:
     to 128 — MXU-aligned.
   * GQA is handled in the K/V index_map (q head h reads kv head
     h // group) — no repeated K/V materialization in HBM.
+  * the wrapper lays Q/K/V out as (B, H, S, D), so every block ends in
+    (rows, D) as the TPU's (8, 128) tiling requires; the online-softmax
+    statistics are (block_q, 1) columns.
   * causal + sliding-window masking is applied per tile; fully-masked
     tiles are skipped with pl.when.
 """
@@ -71,13 +74,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         if window is not None:
             mask = jnp.logical_and(mask, k_pos > q_pos - window)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]                             # (bq,)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                             # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
         m_scr[...] = m_new
-        acc_scr[...] = (acc_scr[...] * corr[:, None]
+        acc_scr[...] = (acc_scr[...] * corr
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -85,7 +88,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[...] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -112,11 +115,13 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     bk = min(block_kv, Skv)
     Sq_p = -(-Sq // bq) * bq
     Skv_p = -(-Skv // bk) * bk
-    if Sq_p != Sq:
-        q = jnp.pad(q, ((0, 0), (0, Sq_p - Sq), (0, 0), (0, 0)))
-    if Skv_p != Skv:
-        k = jnp.pad(k, ((0, 0), (0, Skv_p - Skv), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, Skv_p - Skv), (0, 0), (0, 0)))
+    # (B, H, S, D): each block is a (rows, D) panel of one head
+    q = jnp.pad(q.transpose(0, 2, 1, 3),
+                ((0, 0), (0, 0), (0, Sq_p - Sq), (0, 0)))
+    k = jnp.pad(k.transpose(0, 2, 1, 3),
+                ((0, 0), (0, 0), (0, Skv_p - Skv), (0, 0)))
+    v = jnp.pad(v.transpose(0, 2, 1, 3),
+                ((0, 0), (0, 0), (0, Skv_p - Skv), (0, 0)))
 
     grid = (B, Hq, Sq_p // bq, Skv_p // bk)
     kernel = functools.partial(
@@ -127,21 +132,21 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, bq, None, D),
-                         lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((None, bk, None, D),
-                         lambda b, h, qi, ki: (b, ki, h // group, 0)),
-            pl.BlockSpec((None, bk, None, Dv),
-                         lambda b, h, qi, ki: (b, ki, h // group, 0)),
+            pl.BlockSpec((None, None, bq, D),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((None, None, bk, D),
+                         lambda b, h, qi, ki: (b, h // group, ki, 0)),
+            pl.BlockSpec((None, None, bk, Dv),
+                         lambda b, h, qi, ki: (b, h // group, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((None, bq, None, Dv),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq_p, Hq, Dv), q.dtype),
+        out_specs=pl.BlockSpec((None, None, bq, Dv),
+                               lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq_p, Dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :Sq]
+    return out[:, :, :Sq].transpose(0, 2, 1, 3)

@@ -28,7 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_scr, *,
+def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, h_scr, *,
                 chunk: int):
     ci = pl.program_id(2)
 
@@ -37,35 +37,39 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_scr, *,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     x = x_ref[...].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[...].astype(jnp.float32)        # (Q,)
-    a = a_ref[0]                                # scalar A (negative)
+    dt = dt_ref[...].astype(jnp.float32)        # (Q, 1)
+    a = a_ref[pl.program_id(1)]                 # scalar A (negative)
     b = b_ref[...].astype(jnp.float32)          # (Q, N)
     c = c_ref[...].astype(jnp.float32)          # (Q, N)
 
-    la = dt * a                                 # (Q,) log-decay per step
-    cum = jnp.cumsum(la)                        # (Q,)
-    # intra-chunk decay mask: L[i, j] = exp(cum_i - cum_j), j <= i
-    diff = cum[:, None] - cum[None, :]
+    # every value stays 2-D: cumulative log-decays as a (Q, 1) column and
+    # a (1, Q) row, both from masked reductions (no 1-D vectors, no scan)
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(jj <= ii, jnp.exp(diff), 0.0)
+    la = dt * a                                 # (Q, 1) log-decay per step
+    cum_row = jnp.sum(jnp.where(ii <= jj, la, 0.0), axis=0,
+                      keepdims=True)            # (1, Q)
+    cum = jnp.sum(jnp.where(ii == jj, cum_row, 0.0), axis=1,
+                  keepdims=True)                # (Q, 1)
+    total = jnp.sum(la, axis=0, keepdims=True)  # (1, 1)
+    # intra-chunk decay mask: L[i, j] = exp(cum_i - cum_j), j <= i
+    L = jnp.where(jj <= ii, jnp.exp(cum - cum_row), 0.0)
     scores = jax.lax.dot_general(
         c, b, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)     # (Q, Q)
     w = scores * L
-    xdt = x * dt[:, None]                       # (Q, P)
+    xdt = x * dt                                # (Q, P)
     y = jax.lax.dot_general(w, xdt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     # inter-chunk: h carries (P, N); y += exp(cum) * (C @ h^T)
     h = h_scr[...]
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(cum) * jax.lax.dot_general(
         c, h, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)     # (Q, P)
-    # state update: h' = exp(cum_last) h + sum_i exp(cum_last - cum_i)
+    # state update: h' = exp(total) h + sum_i exp(total - cum_i)
     #               (dt_i x_i) outer B_i
-    rem = cum[-1] - cum                         # (Q,)
-    xw = xdt * jnp.exp(rem)[:, None]            # (Q, P)
-    h_scr[...] = (h * jnp.exp(cum[-1])
+    xw = xdt * jnp.exp(total - cum)             # (Q, P)
+    h_scr[...] = (h * jnp.exp(total)
                   + jax.lax.dot_general(
                       xw, b, (((0,), (0,)), ((), ())),
                       preferred_element_type=jnp.float32))  # (P, N)
@@ -83,12 +87,14 @@ def ssd_scan_pallas(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
     N = b.shape[-1]
     Q = min(chunk, S)
     S_p = -(-S // Q) * Q
-    if S_p != S:
-        x = jnp.pad(x, ((0, 0), (0, S_p - S), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, S_p - S), (0, 0)))
-        b = jnp.pad(b, ((0, 0), (0, S_p - S), (0, 0)))
-        c = jnp.pad(c, ((0, 0), (0, S_p - S), (0, 0)))
-    A = -jnp.exp(a_log.astype(jnp.float32))     # (H,)
+    pad = ((0, 0), (0, 0), (0, S_p - S), (0, 0))
+    # (B, H, S, P) and (B, H, S, 1): each block is a (Q, rows) panel of
+    # one head, as the TPU's (8, 128) tiling requires
+    x = jnp.pad(x.transpose(0, 2, 1, 3), pad)
+    dt = jnp.pad(dt.transpose(0, 2, 1)[..., None], pad)
+    b = jnp.pad(b, pad[1:])
+    c = jnp.pad(c, pad[1:])
+    A = -jnp.exp(a_log.astype(jnp.float32))     # (H,), whole in SMEM
 
     grid = (B, H, S_p // Q)
     kernel = functools.partial(_ssd_kernel, chunk=Q)
@@ -96,17 +102,18 @@ def ssd_scan_pallas(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, Q, None, P), lambda bi, h, ci: (bi, ci, h, 0)),
-            pl.BlockSpec((None, Q, None), lambda bi, h, ci: (bi, ci, h)),
-            pl.BlockSpec((1,), lambda bi, h, ci: (h,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, None, Q, P),
+                         lambda bi, h, ci: (bi, h, ci, 0)),
+            pl.BlockSpec((None, None, Q, 1),
+                         lambda bi, h, ci: (bi, h, ci, 0)),
             pl.BlockSpec((None, Q, N), lambda bi, h, ci: (bi, ci, 0)),
             pl.BlockSpec((None, Q, N), lambda bi, h, ci: (bi, ci, 0)),
         ],
-        out_specs=pl.BlockSpec((None, Q, None, P),
-                               lambda bi, h, ci: (bi, ci, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S_p, H, P), x.dtype),
+        out_specs=pl.BlockSpec((None, None, Q, P),
+                               lambda bi, h, ci: (bi, h, ci, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S_p, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, b, c)
-    return y[:, :S]
+    )(A, x, dt, b, c)
+    return y[:, :, :S].transpose(0, 2, 1, 3)

@@ -149,11 +149,8 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
     dax = daxes if len(daxes) > 1 else daxes[0]
 
     # an active mesh enables PartitionSpec-based shard_hints inside model
-    # code (set_mesh on new jax, the legacy global-mesh context on old)
-    from .mesh import mesh_context
-    mesh_ctx = mesh_context(mesh)
-
-    with mesh_ctx:
+    # code
+    with jax.set_mesh(mesh):
         t0 = time.perf_counter()
         if cell.kind == "train":
             pspecs = param_pspecs(params, cfg, mesh, fsdp=True)

@@ -4,11 +4,18 @@ Defined as FUNCTIONS (never module-level constants) so importing this
 module never touches jax device state — the dry-run sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 BEFORE first jax use,
 and smoke tests must keep seeing 1 device.
+
+Every mesh has ``Auto`` axes: arrays carry their layout in
+``NamedSharding``s and GSPMD propagates it.  ``jax.make_mesh`` defaults to
+``Explicit`` axes, under which the vocab-sharded embedding gather has no
+unambiguous output sharding and fails to trace.  Activate a mesh for the
+model's ``shard_hint``s with ``jax.set_mesh(mesh)``.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,27 +25,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     all-reduce), so adding pods scales capacity elastically."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Generic mesh builder for tests/examples (e.g. ("stage", "model")
     pipeline meshes, or small CPU meshes)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def mesh_context(mesh):
-    """Context manager activating ``mesh`` for PartitionSpec-based
-    sharding constraints, across jax versions: ``jax.sharding.set_mesh``
-    (newest), ``use_mesh`` (transitional), or the legacy global-mesh
-    context (``with mesh:``) on jax <= 0.4.x."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh          # jax.sharding.Mesh is itself a context manager
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple:

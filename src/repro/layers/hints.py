@@ -1,9 +1,11 @@
 """Sharding hints usable from inside model code.
 
-``shard_hint(x, *spec)`` applies a with_sharding_constraint iff an abstract
-mesh is active (jax.sharding.set_mesh context — the launchers set it) AND
-the constraint is valid for x's shape; otherwise it is the identity.  Model
-code stays mesh-agnostic: on a single CPU device every hint is a no-op.
+``shard_hint(x, *spec)`` applies a with_sharding_constraint iff a mesh is
+active (``jax.set_mesh`` — the launchers set it); with no mesh it is the
+identity, so model code stays mesh-agnostic.  Axis entries whose size does
+not divide their dimension are dropped per dimension.  Any other failure
+propagates: a hint that cannot be applied is an error, not a silent
+replication.
 """
 
 from __future__ import annotations
@@ -13,23 +15,12 @@ from jax.sharding import PartitionSpec as P
 
 
 def mesh_axis_size(name: str) -> int:
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is None or am.empty:
-            return 1
-        return dict(am.shape).get(name, 1)
-    except Exception:   # noqa: BLE001 — any mesh-introspection failure
-        return 1
+    return dict(jax.sharding.get_abstract_mesh().shape).get(name, 1)
 
 
 def data_axis_names() -> tuple:
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is None or am.empty:
-            return ()
-        return tuple(a for a in ("pod", "data") if a in am.shape)
-    except Exception:   # noqa: BLE001
-        return ()
+    shape = jax.sharding.get_abstract_mesh().shape
+    return tuple(a for a in ("pod", "data") if a in shape)
 
 
 def _sanitize(x, entries) -> P:
@@ -49,15 +40,11 @@ def _sanitize(x, entries) -> P:
 
 
 def shard_hint(x, *spec_entries):
-    """Best-effort with_sharding_constraint; identity when no mesh, with
-    per-dimension divisibility fallback."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is None or am.empty:
-            return x
-        spec = _sanitize(x, spec_entries)
-        if all(e is None for e in spec):
-            return x
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:   # noqa: BLE001
+    """with_sharding_constraint under the active mesh; identity when no
+    mesh is active, with per-dimension divisibility fallback."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    spec = _sanitize(x, spec_entries)
+    if all(e is None for e in spec):
+        return x
+    return jax.lax.with_sharding_constraint(x, spec)

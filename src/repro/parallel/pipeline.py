@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 
+from repro.launch.mesh import make_mesh
+
 
 def pipeline_forward(stage_fn: Callable, params_stacked, x_micro,
                      mesh: Mesh, n_stages: int,
@@ -89,4 +91,4 @@ def pipeline_forward(stage_fn: Callable, params_stacked, x_micro,
 
 def make_pp_mesh(n_stages: int, tp: int = 1):
     """A (stage, model) mesh from the available devices."""
-    return jax.make_mesh((n_stages, tp), ("stage", "model"))
+    return make_mesh((n_stages, tp), ("stage", "model"))

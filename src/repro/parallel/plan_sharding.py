@@ -25,11 +25,12 @@ import dataclasses
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.planner import ParallelScheme
 from repro.models.config import ModelConfig
-from .sharding import batch_pspec, cache_pspecs, param_pspecs
+from .sharding import batch_pspec, cache_pspecs, param_pspecs, \
+    to_shardings
 
 
 @dataclasses.dataclass
@@ -42,10 +43,12 @@ class MaterializedPlan:
     pp_stages: int
 
     def param_shardings(self, mesh: Optional[Mesh] = None):
-        mesh = mesh or self.mesh
-        return jax.tree.map(lambda s: NamedSharding(mesh, s),
-                            self.param_specs,
-                            is_leaf=lambda s: isinstance(s, P))
+        return to_shardings(self.param_specs, mesh or self.mesh)
+
+    def cache_shardings(self, cache, cfg: ModelConfig):
+        """Decode-cache shardings on this plan's mesh (``cache_pspecs``:
+        batch over the data axes, sequence over "model")."""
+        return to_shardings(cache_pspecs(cache, cfg, self.mesh), self.mesh)
 
 
 def plan_to_shardings(scheme: ParallelScheme, cfg: ModelConfig,

@@ -42,6 +42,7 @@ class _Slot:
     order: int = -1
     arrival: float = 0.0
     first_token_t: Optional[float] = None
+    first_logits: Optional[np.ndarray] = None
     tokens: List[int] = dataclasses.field(default_factory=list)
 
     @property
@@ -64,6 +65,7 @@ class RequestResult:
     e2e: float
     tokens: List[int]
     preemptions: int = 0
+    first_logits: Optional[np.ndarray] = None   # float32 (vocab,)
 
 
 @dataclasses.dataclass
@@ -89,6 +91,10 @@ class EngineReport:
 
 
 class ServingEngine:
+    """Each ``RequestResult`` carries the request's first-token logits as
+    served — what a correctness check compares against a reference forward
+    pass."""
+
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 4,
                  max_len: int = 512, kv_token_budget: Optional[int] = None):
         self.cfg = cfg
@@ -103,7 +109,7 @@ class ServingEngine:
         self.preemptions = 0
         def _step(p, t, c):
             logits, c2 = T.decode_step(p, cfg, t, c)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), c2
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, c2
 
         self._decode = jax.jit(_step)
 
@@ -128,7 +134,10 @@ class ServingEngine:
     def _kv_used(self) -> int:
         return sum(s.kv_tokens for s in self.slots)
 
-    def _admit(self, now: float, records: Dict[int, RequestResult]) -> None:
+    def _admit(self, now: float) -> float:
+        """Admit arrived requests into free slots and prefill each; returns
+        the virtual clock after the prefills.  A request's first token is
+        stamped when its own prefill ends."""
         while self.queue and self.queue[0]["arrival"] <= now:
             req = self.queue[0]
             free = [i for i, s in enumerate(self.slots) if not s.active]
@@ -143,7 +152,11 @@ class ServingEngine:
                                   gen_len=req["gen_len"], order=self._order,
                                   arrival=req["arrival"])
             self._order += 1
+            t0 = time.perf_counter()
             self._prefill_slot(i)
+            now += time.perf_counter() - t0
+            self.slots[i].first_token_t = now
+        return now
 
     def _prefill_slot(self, i: int) -> None:
         """Replay the prompt through the jitted decode step (correctness-
@@ -155,8 +168,8 @@ class ServingEngine:
         for t in range(len(s.prompt)):
             toks = np.zeros((self.max_batch, 1), np.int32)
             toks[i, 0] = s.prompt[t]
-            logits_tok, cache = self._decode(self.params,
-                                             jnp.asarray(toks), self.cache)
+            logits_tok, logits, cache = self._decode(
+                self.params, jnp.asarray(toks), self.cache)
             # only slot i's length may advance
             new_len = np.array(jax.device_get(cache["len"]))
             keep = np.array(jax.device_get(self.cache["len"]))
@@ -166,6 +179,7 @@ class ServingEngine:
         s.generated = 1
         first = int(jax.device_get(logits_tok)[i])
         s.tokens.append(first)
+        s.first_logits = np.asarray(jax.device_get(logits[i]), np.float32)
 
     def _evict_most_recent(self) -> None:
         cand = [s for s in self.slots if s.active]
@@ -197,25 +211,20 @@ class ServingEngine:
         now = 0.0
         iters = 0
         while self.queue or any(s.active for s in self.slots):
-            t0 = time.perf_counter()
-            self._admit(now, records)
+            now = self._admit(now)
             active = [i for i, s in enumerate(self.slots) if s.active]
             if not active:
                 if self.queue:
                     now = max(now, self.queue[0]["arrival"])
                     continue
                 break
-            # mark TTFT for freshly prefilled requests
-            for i in active:
-                s = self.slots[i]
-                if s.first_token_t is None and s.generated >= 1:
-                    s.first_token_t = now
 
+            t0 = time.perf_counter()
             toks = np.zeros((self.max_batch, 1), np.int32)
             for i in active:
                 toks[i, 0] = self.slots[i].tokens[-1]
-            nxt, cache = self._decode(self.params, jnp.asarray(toks),
-                                      self.cache)
+            nxt, _, cache = self._decode(self.params, jnp.asarray(toks),
+                                         self.cache)
             nxt = np.array(jax.device_get(nxt))
             # inactive slots must not advance their length counters
             new_len = np.array(jax.device_get(cache["len"]))
@@ -225,8 +234,7 @@ class ServingEngine:
             new_len = np.where(mask, new_len, old_len)
             cache["len"] = jnp.asarray(new_len)
             self.cache = cache
-            step_t = time.perf_counter() - t0
-            now += step_t
+            now += time.perf_counter() - t0
             iters += 1
 
             for i in active:
@@ -234,12 +242,13 @@ class ServingEngine:
                 s.tokens.append(int(nxt[i]))
                 s.generated += 1
                 if s.generated >= s.gen_len or s.kv_tokens >= self.max_len - 1:
-                    ttft = (s.first_token_t or now) - s.arrival
                     denom = max(s.generated - 1, 1)
                     records[s.rid] = RequestResult(
-                        rid=s.rid, arrival=s.arrival, ttft=ttft,
-                        tpot=(now - (s.first_token_t or now)) / denom,
-                        e2e=now - s.arrival, tokens=list(s.tokens))
+                        rid=s.rid, arrival=s.arrival,
+                        ttft=s.first_token_t - s.arrival,
+                        tpot=(now - s.first_token_t) / denom,
+                        e2e=now - s.arrival, tokens=list(s.tokens),
+                        first_logits=s.first_logits)
                     self.slots[i] = _Slot()
             # KV budget enforcement (greedy batching can overshoot)
             while self._kv_used() > self.kv_budget:

@@ -82,21 +82,11 @@ def _disagg_plan(model, cluster, mode="layerwise"):
     return map_disagg_scheme(scheme, cluster)
 
 
-def _assert_report_matches(rep, want):
-    for field, expect in want.items():
-        if field == "records":
-            got = sorted((r.rid, r.first_token_time, r.finish_time,
-                          r.preemptions, r.refetch_s) for r in rep.records)
-            assert got == [tuple(r) for r in expect]
-        else:
-            assert getattr(rep, field) == expect, field
-
-
 # ---------------------------------------------------------------------------
 # determinism: empty schedule == frozen goldens; same seed == same bits
 # ---------------------------------------------------------------------------
 
-def test_empty_schedule_matches_colocated_goldens_exactly(golden, ctx):
+def test_empty_schedule_matches_colocated_goldens_exactly(golden, ctx, report_matches):
     """faults=FaultSchedule() must be invisible: every frozen colocated
     golden case reproduces bit for bit with the (empty) schedule
     threaded through the whole fault plumbing."""
@@ -110,11 +100,11 @@ def test_empty_schedule_matches_colocated_goldens_exactly(golden, ctx):
         sim = PlanSimulator(plans[case["dp"]], store, coll)
         rep = sim.simulate(reqs, policy=POLICIES[case["policy"]],
                            keep_records=True, faults=empty)
-        _assert_report_matches(rep, case["report"])
+        report_matches(rep, case["report"])
         assert rep.resilience is None
 
 
-def test_empty_schedule_matches_disagg_goldens_exactly(golden, ctx):
+def test_empty_schedule_matches_disagg_goldens_exactly(golden, ctx, report_matches):
     model, cluster, store, coll = ctx
     for case in golden["disagg"]:
         plan = _disagg_plan(model, cluster, case["mode"])
@@ -124,7 +114,7 @@ def test_empty_schedule_matches_disagg_goldens_exactly(golden, ctx):
         rep = sim.simulate(reqs, keep_records=True, congestion=False,
                            reprefill_occupancy=False,
                            faults=FaultSchedule())
-        _assert_report_matches(rep, case["report"])
+        report_matches(rep, case["report"])
         assert rep.resilience is None
 
 

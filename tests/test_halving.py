@@ -306,6 +306,16 @@ def test_fork_map_spawn_only_falls_back_serial(monkeypatch):
     assert out == [i * i for i in range(6)]
 
 
+def test_fork_map_refuses_to_fork_a_process_holding_the_chip(monkeypatch):
+    """Forked workers would inherit the parent's accelerator client: once
+    a non-CPU backend is up, jobs>1 raises; jobs=1 still runs serially."""
+    import repro.core.search as search_mod
+    monkeypatch.setattr(search_mod, "_holds_accelerator", lambda: True)
+    with pytest.raises(RuntimeError, match="inherit the chip"):
+        fork_map(lambda i: i, 4, jobs=2)
+    assert fork_map(lambda i: i + 1, 3, jobs=1) == [1, 2, 3]
+
+
 def test_fork_map_spawn_only_search_still_works(monkeypatch):
     monkeypatch.setattr(multiprocessing, "get_all_start_methods",
                         lambda: ["spawn"])

@@ -111,3 +111,18 @@ def test_full_config_ir_sizes():
     for arch, (lo, hi) in expect.items():
         n = C.get_config(arch).to_ir().total_params() / 1e9
         assert lo <= n <= hi, f"{arch}: {n:.1f}B outside [{lo},{hi}]"
+
+
+def test_shard_hint_is_identity_only_without_a_mesh():
+    """No mesh: every hint is the identity.  Under a mesh, a hint that
+    cannot be applied raises instead of silently replicating."""
+    from repro.launch.mesh import make_mesh
+    from repro.layers.hints import data_axis_names, mesh_axis_size, \
+        shard_hint
+    x = jnp.ones((4, 8))
+    assert shard_hint(x, "data", "model") is x
+    assert mesh_axis_size("model") == 1 and data_axis_names() == ()
+    with jax.set_mesh(make_mesh((1, 1), ("data", "model"))):
+        assert data_axis_names() == ("data",)
+        with pytest.raises(Exception, match="no_such_axis"):
+            jax.jit(lambda y: shard_hint(y, "no_such_axis", None))(x)

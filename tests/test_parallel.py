@@ -36,9 +36,10 @@ def test_ep_matches_dense_oracle(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh
 from repro.layers.moe import init_moe, moe_forward
+from repro.launch.mesh import make_mesh
 from repro.parallel.ep import moe_ep_forward
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = jax.random.PRNGKey(0)
 d, f, E, k = 16, 32, 8, 2
 params = init_moe(rng, d, f, E, k, dtype=jnp.float32)
@@ -58,8 +59,9 @@ def test_sp_decode_matches_ref(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from repro.parallel.sp_decode import sp_decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 B, Hq, Hkv, D, Smax = 4, 8, 2, 16, 64
 q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
@@ -78,13 +80,14 @@ def test_elastic_reshard_roundtrip(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.training.elastic import reshard_state
 
 state = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
          "b": jnp.ones((8,))}
 specs = {"w": P("data", "model"), "b": P("model")}
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))   # "node failure" remesh
+mesh_a = make_mesh((4, 2), ("data", "model"))
+mesh_b = make_mesh((2, 4), ("data", "model"))   # "node failure" remesh
 on_a = reshard_state(state, specs, mesh_a)
 on_b = reshard_state(on_a, specs, mesh_b)
 for k in state:
@@ -127,13 +130,14 @@ def test_distributed_train_step_runs(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs as C
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import transformer as T
 from repro.parallel.sharding import param_pspecs
 from repro.training.optimizer import adamw_init
 
 cfg = C.get_reduced("internlm2_1_8b")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 params = T.init_params(jax.random.PRNGKey(0), cfg)
 opt = adamw_init(params)
 step = make_train_step(cfg, microbatches=1, remat=True)
@@ -151,8 +155,7 @@ ps = jax.device_put(params, sh(pspecs))
 ospecs = type(opt)(master=pspecs, m=pspecs, v=pspecs, step=P())
 os_ = jax.device_put(opt, sh(ospecs))
 bs = jax.device_put(batch, NamedSharding(mesh, P("data", None)))
-from repro.launch.mesh import mesh_context
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     p2, o2, m2 = jax.jit(step, in_shardings=(sh(pspecs), sh(ospecs),
                          NamedSharding(mesh, P("data", None))))(ps, os_, bs)
 assert abs(float(m1["loss"]) - float(m2["loss"])) < 5e-2, \

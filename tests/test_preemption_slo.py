@@ -87,16 +87,6 @@ def _disagg_scheme(model, cluster, mode):
     raise RuntimeError("no disagg scheme")
 
 
-def _assert_report_matches(rep, want):
-    for field, expect in want.items():
-        if field == "records":
-            got = sorted((r.rid, r.first_token_time, r.finish_time,
-                          r.preemptions, r.refetch_s) for r in rep.records)
-            assert got == [tuple(r) for r in expect]
-        else:
-            assert getattr(rep, field) == expect, field
-
-
 def const_cost(per_token=1e-3, per_iter=5e-3):
     def step_cost(w):
         t = per_iter + per_token * w.total_tokens
@@ -114,7 +104,7 @@ def mk_requests(specs, slo=None):
 # golden pin: explicit sacrifice + a single class == the frozen engine
 # ---------------------------------------------------------------------------
 
-def test_sacrifice_single_class_matches_colocated_goldens(golden, ctx):
+def test_sacrifice_single_class_matches_colocated_goldens(golden, ctx, report_matches):
     model, cluster, store, coll = ctx
     plans = {dp: map_scheme(_colocated_scheme(model, dp), cluster)
              for dp in (1, 2)}
@@ -125,10 +115,10 @@ def test_sacrifice_single_class_matches_colocated_goldens(golden, ctx):
         rep = sim.simulate(reqs, policy=POLICIES[case["policy"]],
                            keep_records=True, preemption="sacrifice",
                            slo_classes=ONE_TENANT)
-        _assert_report_matches(rep, case["report"])
+        report_matches(rep, case["report"])
 
 
-def test_sacrifice_single_class_matches_disagg_goldens(golden, ctx):
+def test_sacrifice_single_class_matches_disagg_goldens(golden, ctx, report_matches):
     model, cluster, store, coll = ctx
     for case in golden["disagg"]:
         scheme = _disagg_scheme(model, cluster, case["mode"])
@@ -139,7 +129,7 @@ def test_sacrifice_single_class_matches_disagg_goldens(golden, ctx):
         rep = sim.simulate(reqs, keep_records=True, congestion=False,
                            reprefill_occupancy=False,
                            preemption="sacrifice", slo_classes=ONE_TENANT)
-        _assert_report_matches(rep, case["report"])
+        report_matches(rep, case["report"])
 
 
 # ---------------------------------------------------------------------------

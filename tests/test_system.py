@@ -100,3 +100,41 @@ def test_batching_policy_max_batch(llama):
         reqs, policy=BatchingPolicy(max_batch_size=2))
     assert capped.peak_batch <= 2
     assert capped.e2e_latency >= uncapped.e2e_latency * 0.999
+
+
+# ---------------------------------------------------------------------------
+# v5e cluster presets and the device-kind table
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name,chips", [("tpu-v5e-1", 1), ("tpu-v5e-4", 4)])
+def test_tpu_v5e_host_presets(name, chips):
+    from repro.core import get_cluster
+    from repro.core.cluster import TPU_V5E
+    clu = get_cluster(name)
+    assert clu.num_devices == chips and clu.device is TPU_V5E
+    assert clu.level_for_group(chips).group_size >= chips
+
+
+def test_device_kind_table_has_no_default():
+    from repro.core.cluster import TPU_V5E, cluster_for_device_kind
+    assert cluster_for_device_kind("TPU v5 lite").device is TPU_V5E
+    assert cluster_for_device_kind("cpu").name == "cpu-local"
+    with pytest.raises(KeyError, match="TPU v6 lite"):
+        cluster_for_device_kind("TPU v6 lite")
+
+
+def test_measured_backend_times_in_the_ops_dtype():
+    """The profiler pairs its timings with this device's DeviceSpec and
+    runs each op in its compute dtype; a dtype it cannot run raises."""
+    import jax
+    from repro.core import MeasuredBackend
+    be = MeasuredBackend(repeats=1)
+    assert be.cluster.device.name == {
+        "cpu": "cpu-local", "TPU v5 lite": "TPU-v5e"}[
+            jax.devices()[0].device_kind]
+    fn, (a, b) = be._build("gemm", (32, 16, "bf16"), 8.0)
+    assert a.dtype == b.dtype == jax.numpy.bfloat16
+    t, e = be.measure("attn_decode", (2, 16, "bf16"), 64.0)
+    assert t > 0 and e > 0
+    with pytest.raises(KeyError, match="fp8"):
+        be.measure("gemm", (32, 16, "fp8"), 8.0)
