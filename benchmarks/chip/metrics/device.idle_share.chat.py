@@ -1,0 +1,5 @@
+"""Device: share of the traced window in which no operation ran."""
+
+
+def read(ctx):
+    return None if ctx.trace is None else 100.0 * ctx.trace["idle_share"]
