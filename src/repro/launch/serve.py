@@ -22,6 +22,7 @@ from repro.core import ApexSearch, get_cluster, get_trace
 from repro.data.requests import make_serving_requests
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
+from repro.serving import telemetry
 from repro.serving.engine import ServingEngine
 
 
@@ -47,8 +48,8 @@ def serve(arch: str = "internlm2_1_8b", cfg: Optional[ModelConfig] = None,
     with ``cfg`` (default: ``arch``'s FULL config) on this process's
     device, weights drawn from ``PRNGKey(seed)``.  Prompts are cut to
     ``max_prompt`` and generations to ``max_gen`` tokens (both default to
-    ``max_len // 4``).  Returns (baseline report, search result, engine
-    report)."""
+    ``max_len // 4``), and the engine's spans are summed up in one line.
+    Returns (baseline report, search result, engine report)."""
     cfg = cfg or C.get_config(arch)
     if cfg.encoder is not None or cfg.embeds_input:
         raise ValueError(f"{cfg.name}: the serving engine takes token ids "
@@ -71,16 +72,15 @@ def serve(arch: str = "internlm2_1_8b", cfg: Optional[ModelConfig] = None,
 
     # 2) serve cfg on this process's device
     params = T.init_params(jax.random.PRNGKey(seed), cfg)
-    engine = ServingEngine(cfg, params, max_batch=max_batch, max_len=max_len)
+    recorder = telemetry.EngineTrace()
+    engine = ServingEngine(cfg, params, max_batch=max_batch, max_len=max_len,
+                           trace=recorder)
     rqs = serving_requests(cfg, trace, requests, arrival_rate,
                            max_prompt or max_len // 4,
                            max_gen or max_len // 4, seed)
     report = engine.run(rqs, time_scale=0.0)   # all arrive at t=0
-    log(f"engine [{cfg.name}]: {len(report.results)} requests in "
-        f"{report.total_time:.1f}s, {report.iterations} iterations, "
-        f"TTFT {report.ttft_mean * 1e3:.0f}ms TPOT "
-        f"{report.tpot_mean * 1e3:.0f}ms "
-        f"throughput {report.throughput:.1f} tok/s (host clock)")
+    log(f"engine [{cfg.name}]: {len(report.results)} requests, "
+        f"{telemetry.summary(recorder.spans)}")
     return base, best, report
 
 

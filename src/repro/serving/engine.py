@@ -17,6 +17,10 @@ Implements iteration-level batching over a slot-based KV cache:
 
 Checkpointable: ``snapshot()``/``restore()`` capture queued + in-flight
 request state so a restarted replica replays its work (fault tolerance).
+
+Given an ``EngineTrace`` the engine records a span at each layer boundary
+(prompt replay, decode step and their host syncs, eviction); ``telemetry``
+names them and their counts.
 """
 
 from __future__ import annotations
@@ -31,6 +35,7 @@ import numpy as np
 
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
+from repro.serving.telemetry import OFF, EngineTrace
 
 
 @dataclasses.dataclass
@@ -75,29 +80,17 @@ class EngineReport:
     iterations: int
     preemptions: int
 
-    @property
-    def ttft_mean(self) -> float:
-        return float(np.mean([r.ttft for r in self.results]))
-
-    @property
-    def tpot_mean(self) -> float:
-        ts = [r.tpot for r in self.results if r.tpot > 0]
-        return float(np.mean(ts)) if ts else 0.0
-
-    @property
-    def throughput(self) -> float:
-        toks = sum(len(r.tokens) for r in self.results)
-        return toks / self.total_time if self.total_time else 0.0
-
 
 class ServingEngine:
     """Each ``RequestResult`` carries the request's first-token logits as
     served — what a correctness check compares against a reference forward
-    pass."""
+    pass.  ``trace``, when given, records the engine's spans."""
 
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 4,
-                 max_len: int = 512, kv_token_budget: Optional[int] = None):
+                 max_len: int = 512, kv_token_budget: Optional[int] = None,
+                 trace: Optional[EngineTrace] = None):
         self.cfg = cfg
+        self.trace = trace
         self.params = params
         self.max_batch = max_batch
         self.max_len = max_len
@@ -138,6 +131,7 @@ class ServingEngine:
         """Admit arrived requests into free slots and prefill each; returns
         the virtual clock after the prefills.  A request's first token is
         stamped when its own prefill ends."""
+        tr = self.trace
         while self.queue and self.queue[0]["arrival"] <= now:
             req = self.queue[0]
             free = [i for i, s in enumerate(self.slots) if not s.active]
@@ -153,7 +147,11 @@ class ServingEngine:
                                   arrival=req["arrival"])
             self._order += 1
             t0 = time.perf_counter()
-            self._prefill_slot(i)
+            with OFF if tr is None else tr.span(
+                    "engine.prefill", rid=req["rid"],
+                    tokens=len(req["prompt"]),
+                    waited_s=now - req["arrival"]):
+                self._prefill_slot(i)
             now += time.perf_counter() - t0
             self.slots[i].first_token_t = now
         return now
@@ -162,36 +160,43 @@ class ServingEngine:
         """Replay the prompt through the jitted decode step (correctness-
         first prefill; the whole batch's other slots ride along masked)."""
         s = self.slots[i]
-        lens = np.array(jax.device_get(self.cache["len"]))
-        lens[i] = 0
-        self.cache["len"] = jnp.asarray(lens)
+        tr = self.trace
+        with OFF if tr is None else tr.span("engine.prefill.sync"):
+            lens = np.array(jax.device_get(self.cache["len"]))
+            lens[i] = 0
+            self.cache["len"] = jnp.asarray(lens)
         for t in range(len(s.prompt)):
             toks = np.zeros((self.max_batch, 1), np.int32)
             toks[i, 0] = s.prompt[t]
             logits_tok, logits, cache = self._decode(
                 self.params, jnp.asarray(toks), self.cache)
-            # only slot i's length may advance
-            new_len = np.array(jax.device_get(cache["len"]))
-            keep = np.array(jax.device_get(self.cache["len"]))
-            keep[i] = new_len[i]
-            cache["len"] = jnp.asarray(keep)
+            with OFF if tr is None else tr.span("engine.prefill.sync"):
+                # only slot i's length may advance
+                new_len = np.array(jax.device_get(cache["len"]))
+                keep = np.array(jax.device_get(self.cache["len"]))
+                keep[i] = new_len[i]
+                cache["len"] = jnp.asarray(keep)
             self.cache = cache
         s.generated = 1
-        first = int(jax.device_get(logits_tok)[i])
+        with OFF if tr is None else tr.span("engine.prefill.sync"):
+            first = int(jax.device_get(logits_tok)[i])
+            s.first_logits = np.asarray(jax.device_get(logits[i]),
+                                        np.float32)
         s.tokens.append(first)
-        s.first_logits = np.asarray(jax.device_get(logits[i]), np.float32)
 
     def _evict_most_recent(self) -> None:
         cand = [s for s in self.slots if s.active]
         if not cand:
             return
         victim = max(cand, key=lambda s: s.order)
-        idx = self.slots.index(victim)
-        self.queue.insert(0, dict(rid=victim.rid, prompt=victim.prompt,
-                                  gen_len=victim.gen_len,
-                                  arrival=victim.arrival))
-        self.preemptions += 1
-        self.slots[idx] = _Slot()
+        tr = self.trace
+        with OFF if tr is None else tr.span("engine.evict"):
+            idx = self.slots.index(victim)
+            self.queue.insert(0, dict(rid=victim.rid, prompt=victim.prompt,
+                                      gen_len=victim.gen_len,
+                                      arrival=victim.arrival))
+            self.preemptions += 1
+            self.slots[idx] = _Slot()
 
     # -- main loop -------------------------------------------------------------
 
@@ -210,6 +215,7 @@ class ServingEngine:
                                first=None, start=None) for r in requests}
         now = 0.0
         iters = 0
+        tr = self.trace
         while self.queue or any(s.active for s in self.slots):
             now = self._admit(now)
             active = [i for i, s in enumerate(self.slots) if s.active]
@@ -218,14 +224,30 @@ class ServingEngine:
                     now = max(now, self.queue[0]["arrival"])
                     continue
                 break
+            with OFF if tr is None else tr.span(
+                    "engine.decode", active=len(active),
+                    kv_tokens=self._kv_used(),
+                    kv_reserved=self.max_batch * self.max_len):
+                now = self._decode_iteration(active, now, records)
+            iters += 1
 
-            t0 = time.perf_counter()
-            toks = np.zeros((self.max_batch, 1), np.int32)
-            for i in active:
-                toks[i, 0] = self.slots[i].tokens[-1]
+        return EngineReport(results=list(records.values()), total_time=now,
+                            iterations=iters, preemptions=self.preemptions)
+
+    def _decode_iteration(self, active: List[int], now: float,
+                          records: Dict[int, RequestResult]) -> float:
+        """One decode step over the ``active`` slots; records the requests
+        it finishes and returns the virtual clock after the step."""
+        tr = self.trace
+        t0 = time.perf_counter()
+        toks = np.zeros((self.max_batch, 1), np.int32)
+        for i in active:
+            toks[i, 0] = self.slots[i].tokens[-1]
+        with OFF if tr is None else tr.span("engine.decode.call"):
             nxt, _, cache = self._decode(self.params, jnp.asarray(toks),
                                          self.cache)
             nxt = np.array(jax.device_get(nxt))
+        with OFF if tr is None else tr.span("engine.decode.sync"):
             # inactive slots must not advance their length counters
             new_len = np.array(jax.device_get(cache["len"]))
             old_len = np.array(jax.device_get(self.cache["len"]))
@@ -233,26 +255,23 @@ class ServingEngine:
             mask[active] = True
             new_len = np.where(mask, new_len, old_len)
             cache["len"] = jnp.asarray(new_len)
-            self.cache = cache
-            now += time.perf_counter() - t0
-            iters += 1
+        self.cache = cache
+        now += time.perf_counter() - t0
 
-            for i in active:
-                s = self.slots[i]
-                s.tokens.append(int(nxt[i]))
-                s.generated += 1
-                if s.generated >= s.gen_len or s.kv_tokens >= self.max_len - 1:
-                    denom = max(s.generated - 1, 1)
-                    records[s.rid] = RequestResult(
-                        rid=s.rid, arrival=s.arrival,
-                        ttft=s.first_token_t - s.arrival,
-                        tpot=(now - s.first_token_t) / denom,
-                        e2e=now - s.arrival, tokens=list(s.tokens),
-                        first_logits=s.first_logits)
-                    self.slots[i] = _Slot()
-            # KV budget enforcement (greedy batching can overshoot)
-            while self._kv_used() > self.kv_budget:
-                self._evict_most_recent()
-
-        return EngineReport(results=list(records.values()), total_time=now,
-                            iterations=iters, preemptions=self.preemptions)
+        for i in active:
+            s = self.slots[i]
+            s.tokens.append(int(nxt[i]))
+            s.generated += 1
+            if s.generated >= s.gen_len or s.kv_tokens >= self.max_len - 1:
+                denom = max(s.generated - 1, 1)
+                records[s.rid] = RequestResult(
+                    rid=s.rid, arrival=s.arrival,
+                    ttft=s.first_token_t - s.arrival,
+                    tpot=(now - s.first_token_t) / denom,
+                    e2e=now - s.arrival, tokens=list(s.tokens),
+                    first_logits=s.first_logits)
+                self.slots[i] = _Slot()
+        # KV budget enforcement (greedy batching can overshoot)
+        while self._kv_used() > self.kv_budget:
+            self._evict_most_recent()
+        return now

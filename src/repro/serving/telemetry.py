@@ -1,0 +1,155 @@
+"""Spans the serving engine records at its layer boundaries.
+
+``ServingEngine(..., trace=EngineTrace())`` records one ``Span`` each time
+it crosses a boundary: the name, start and end on ``time.perf_counter()``,
+the span that was open when it started (``parent``, an index into
+``EngineTrace.spans``), the request it serves (``rid``; a span given none
+takes its parent's, so the spans of one request share it) and ``attrs``,
+the counts taken at that boundary.  Each span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so under a profiler
+trace the same spans land in the host plane, on the device trace's clock.
+With no recorder (``trace=None``, the default) a span site costs one
+``is None`` check: nothing is allocated, annotated or synced.  The caller
+owns the recorder and drops it when done.
+
+The names and attrs are an interface.  A later change that rebuilds a
+layer (a batched prefill, a donated cache, fewer host syncs) keeps
+emitting its span under the same name with the same attrs, so that what
+reads them goes on reading the same quantity.
+
+=======================  ===================================================
+``engine.prefill``       one request's prompt replay; ``tokens`` replayed,
+                         ``waited_s`` (engine clock at admission minus the
+                         request's arrival)
+``engine.prefill.sync``  the replay's host syncs: the length reset, the
+                         per-token length fix-up, the first token and
+                         logits fetch
+``engine.decode``        one decode iteration; ``active`` slots,
+                         ``kv_tokens`` in use over all slots,
+                         ``kv_reserved`` (``max_batch * max_len``)
+``engine.decode.call``   the step call and its tokens' fetch: the host
+                         waiting on the device
+``engine.decode.sync``   the length fix-up: two fetches, one upload
+``engine.evict``         one preemption
+=======================  ===================================================
+
+Each span and attr has a reader: ``summary`` here, an operator's line.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import statistics
+import time
+from typing import Dict, Iterator, List, Optional, Sequence
+
+import jax
+
+SPAN_NAMES = ("engine.prefill", "engine.prefill.sync", "engine.decode",
+              "engine.decode.call", "engine.decode.sync", "engine.evict")
+
+# What a span site enters when no recorder is given: stateless, so shared.
+OFF = contextlib.nullcontext()
+
+
+@dataclasses.dataclass
+class Span:
+    name: str
+    start: float                   # time.perf_counter()
+    end: float
+    parent: Optional[int]          # index in EngineTrace.spans; None: a root
+    rid: Optional[int]
+    attrs: Dict[str, float]
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
+class EngineTrace:
+    """In-memory record of one engine's spans, in the order they opened."""
+
+    def __init__(self):
+        self.spans: List[Span] = []
+        self._open: List[int] = []
+
+    @contextlib.contextmanager
+    def span(self, name: str, rid: Optional[int] = None,
+             **attrs) -> Iterator[None]:
+        parent = self._open[-1] if self._open else None
+        if rid is None and parent is not None:
+            rid = self.spans[parent].rid
+        sp = Span(name, 0.0, 0.0, parent, rid, attrs)
+        self._open.append(len(self.spans))
+        self.spans.append(sp)
+        with jax.profiler.TraceAnnotation(name):
+            sp.start = time.perf_counter()
+            try:
+                yield
+            finally:
+                sp.end = time.perf_counter()
+                self._open.pop()
+
+
+# ---------------------------------------------------------------------------
+# the operator's line
+# ---------------------------------------------------------------------------
+
+def _decode_host_gaps(spans: Sequence[Span]) -> List[float]:
+    """Seconds from each ``engine.decode.call``'s end to the next one's
+    start, over consecutive decode iterations with no prefill between: the
+    time the device waits on the host in each step."""
+    gaps, last = [], None
+    for sp in sorted(spans, key=lambda s: s.start):
+        if sp.name == "engine.prefill":
+            last = None
+        elif sp.name == "engine.decode.call":
+            if last is not None:
+                gaps.append(sp.start - last)
+            last = sp.end
+    return gaps
+
+
+def _per_prefill_token(spans: Sequence[Span], name: str) -> Optional[float]:
+    """Seconds in spans called ``name`` per prompt token replayed."""
+    tokens = sum(s.attrs["tokens"] for s in spans
+                 if s.name == "engine.prefill")
+    return sum(s.seconds for s in spans if s.name == name) / tokens \
+        if tokens else None
+
+
+def _median(xs: Sequence[float]) -> Optional[float]:
+    return statistics.median(xs) if xs else None
+
+
+def _seconds(spans: Sequence[Span], name: str) -> List[float]:
+    return [s.seconds for s in spans if s.name == name]
+
+
+def summary(spans: Sequence[Span]) -> str:
+    """One line for an operator (host clock, milliseconds): decode
+    iterations and their mean active slots; per step the median host time
+    between step calls, the median wait on the device and the median length
+    fix-up; mean KV in use over reserved; per replayed prompt token the
+    replay and its host syncs; median admission wait; preemptions."""
+    def ms(x):
+        return "-" if x is None else f"{x * 1e3:.2f}"
+    decodes = [s for s in spans if s.name == "engine.decode"]
+    active = statistics.fmean(s.attrs["active"] for s in decodes) \
+        if decodes else 0.0
+    kv = statistics.fmean(s.attrs["kv_tokens"] / s.attrs["kv_reserved"]
+                          for s in decodes) if decodes else None
+    waits = [s.attrs["waited_s"] for s in spans if s.name == "engine.prefill"]
+    return (f"{len(decodes)} decode iterations, {active:.1f} slots active: "
+            f"host {ms(_median(_decode_host_gaps(spans)))} ms per step "
+            f"against {ms(_median(_seconds(spans, 'engine.decode.call')))} "
+            f"ms waiting on the device, length fix-up "
+            f"{ms(_median(_seconds(spans, 'engine.decode.sync')))} ms, KV "
+            f"in use {'-' if kv is None else f'{kv:.1%}'} of reserved; "
+            f"prefill {ms(_per_prefill_token(spans, 'engine.prefill'))} ms "
+            f"per token, of it host syncs "
+            f"{ms(_per_prefill_token(spans, 'engine.prefill.sync'))} ms, "
+            f"admission wait median {ms(_median(waits))} ms; "
+            f"{sum(s.name == 'engine.evict' for s in spans)} preemptions "
+            f"(host clock)")

@@ -103,7 +103,8 @@ def test_engine_matches_model_decode(small):
 def test_serve_reduced_config_logits_match_float32_forward():
     """``serve()`` runs the config it is given; each request's first-token
     logits as served (bf16) agree with a float32 ``forward`` of its prompt
-    on the same weights — the check chip_smoke.py makes at FULL width."""
+    on the same weights — the check chip_smoke.py makes at FULL width.  It
+    sums up the engine's spans in one line."""
     import dataclasses
 
     import jax.numpy as jnp
@@ -111,9 +112,11 @@ def test_serve_reduced_config_logits_match_float32_forward():
 
     from repro.launch.serve import serve, serving_requests
     cfg = C.get_reduced("internlm2_1_8b")
+    logs = []
     _, _, rep = serve("internlm2_1_8b", cfg, trace="creation", requests=3,
                       cluster="tpu-v5e-1", max_batch=2, max_len=64,
-                      max_prompt=12, max_gen=5, seed=3, log=lambda m: None)
+                      max_prompt=12, max_gen=5, seed=3, log=logs.append)
+    assert f"3 requests, {rep.iterations} decode iterations, " in logs[-1]
     rqs = {r["rid"]: r for r in serving_requests(cfg, "creation", 3, 2.0,
                                                  12, 5, seed=3)}
     assert sorted(r.rid for r in rep.results) == sorted(rqs)
