@@ -340,29 +340,31 @@ def gqa_decode_step(params: dict, x: jnp.ndarray, cache_k: jnp.ndarray,
             c, vv.astype(c.dtype), (i, 0, 0))
     )(cache_v, v, idx)
 
-    rep = n_heads // n_kv_heads
-    scale = 1.0 / math.sqrt(head_dim)
-    # fp8 caches are upcast to the compute dtype on read.  The shard hints
-    # pin the GQA repeat and the score matrix to the cache's SEQUENCE
-    # sharding, making the softmax+readout a flash-decoding combine (psum
-    # of small (B,H) stats + (B,H,D) partials) instead of a per-layer KV
-    # all-gather — §Perf iteration 3 (no-ops off-mesh).
+    # Grouped by KV head: query head h attends with KV head h // g, so q is
+    # viewed as (B, 1, Hkv, g, D) and each cached K/V entry is read once —
+    # no (B, Smax, Hq, D) repeat is built.  fp8 caches are upcast to the
+    # compute dtype on read (a no-op for a cache already in it).  The shard
+    # hints pin the cache and the scores to the cache's SEQUENCE sharding,
+    # making the softmax+readout a flash-decoding combine (psum of small
+    # (B,H) stats + (B,H,D) partials) instead of a per-layer KV all-gather
+    # — §Perf iteration 3 (no-ops off-mesh).
     from .hints import data_axis_names, shard_hint
     daxes = data_axis_names() or None
-    kr = jnp.repeat(cache_k.astype(q.dtype), rep, axis=2)  # (B, Smax, Hq, D)
-    vr = jnp.repeat(cache_v.astype(q.dtype), rep, axis=2)
-    kr = shard_hint(kr, daxes, "model", None, None)
-    vr = shard_hint(vr, daxes, "model", None, None)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
+    g = n_heads // n_kv_heads
+    scale = 1.0 / math.sqrt(head_dim)
+    qg = q.reshape(B, 1, n_kv_heads, g, head_dim)
+    kc = shard_hint(cache_k.astype(q.dtype), daxes, "model", None, None)
+    vc = shard_hint(cache_v.astype(q.dtype), daxes, "model", None, None)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kc,
                    preferred_element_type=jnp.float32) * scale
-    s = shard_hint(s, daxes, None, None, "model")
+    s = shard_hint(s, daxes, None, None, None, "model")
     k_slot = jnp.arange(Smax)[None, :]                     # (1, Smax)
     n_valid = jnp.minimum(cache_len + 1, Smax)             # (B,)
     valid = k_slot < n_valid[:, None]
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(vr.dtype)
-    p = shard_hint(p, daxes, None, None, "model")
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, vr)
+    s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(vc.dtype)
+    p = shard_hint(p, daxes, None, None, None, "model")
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, vc)
     y = out.reshape(B, 1, n_heads * head_dim) @ params["wo"]
     return y, cache_k, cache_v
 
