@@ -1,15 +1,22 @@
 """deepseek-v2-lite-16b [moe] — 27L d_model=2048 16H, MLA (kv_lora=512),
 MoE: 64 routed experts top-6 + 2 shared, expert d_ff=1408, vocab=102400.
 First layer uses a dense FFN (d_ff=10944), per the released config.
-[arXiv:2405.04434; hf]
+Router gates: softmax over all 64 experts, top 6 kept as they are
+(``norm_topk_prob: false``, ``routed_scaling_factor`` 1).  RoPE on the
+64-wide rope part is YaRN-scaled (factor 40 over 4096 original positions,
+beta 32/1, mscale = mscale_all_dim = 0.707), which also multiplies the
+softmax scale by mscale^2.  [arXiv:2405.04434; hf config.json]
 
 MLA's latent KV cache is NOT head-sharded: the TP template shards query
-heads / up-projections and replicates the 512-rank latent (DESIGN.md
-§Arch-applicability).  MLA is still full attention over the sequence ->
-long_500k SKIPPED.
+heads / up-projections and replicates the 512-rank latent.  MLA is still
+full attention over the sequence -> long_500k SKIPPED.
 """
 
-from repro.models.config import LayerSpec, ModelConfig
+from repro.models.config import LayerSpec, ModelConfig, RopeScaling
+
+YARN = RopeScaling(factor=40.0, original_max_position_embeddings=4096,
+                   beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                   mscale_all_dim=0.707)
 
 FULL = ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -23,11 +30,13 @@ FULL = ModelConfig(
     qk_nope_head_dim=128,
     qk_rope_head_dim=64,
     v_head_dim=128,
+    rope_scaling=YARN,
     ffn_kind="moe",
     n_routed=64,
     top_k=6,
     n_shared=2,
     d_ff_expert=1408,
+    norm_topk_prob=False,
     first_k_dense=1,
     d_ff_dense_first=10944,
     d_ff=1408,
@@ -45,11 +54,13 @@ REDUCED = ModelConfig(
     qk_nope_head_dim=16,
     qk_rope_head_dim=8,
     v_head_dim=16,
+    rope_scaling=YARN,
     ffn_kind="moe",
     n_routed=8,
     top_k=2,
     n_shared=1,
     d_ff_expert=48,
+    norm_topk_prob=False,
     first_k_dense=1,
     d_ff_dense_first=96,
     d_ff=48,

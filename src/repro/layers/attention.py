@@ -21,7 +21,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .rope import apply_mrope, apply_rope
+from .norms import rms_norm
+from .rope import apply_mrope, apply_rope, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -64,6 +65,7 @@ def init_mla(rng, d_model: int, n_heads: int, kv_lora_rank: int,
         "wdkv": (jax.random.normal(
             k2, (d_model, kv_lora_rank + qk_rope_head_dim)) * s
         ).astype(dtype),
+        "kv_norm": jnp.ones((kv_lora_rank,), dtype),
         "wukv": (jax.random.normal(
             k3, (kv_lora_rank, n_heads * (qk_nope_head_dim + v_head_dim)))
             * (1.0 / math.sqrt(kv_lora_rank))).astype(dtype),
@@ -382,10 +384,28 @@ def _mla_expand(params: dict, c_kv: jnp.ndarray, n_heads: int,
     return u[..., :qk_nope], u[..., qk_nope:]
 
 
+def _mla_latent(params: dict, x: jnp.ndarray, kv_lora_rank: int):
+    """The normalised latent c_kv (B, S, r) and the un-rotated rope key
+    k_pe (B, S, dr): ``kv_a_layernorm`` applies to the latent only."""
+    dkv = x @ params["wdkv"]                               # (B,S,r+dr)
+    return (rms_norm(dkv[..., :kv_lora_rank], params["kv_norm"]),
+            dkv[..., kv_lora_rank:])
+
+
+def _yarn_softmax_factor(rope_scaling) -> float:
+    """What YaRN multiplies MLA's softmax scale qk_head^-0.5 by:
+    mscale(factor, mscale_all_dim)^2 (1 without YaRN)."""
+    if rope_scaling is None:
+        return 1.0
+    return yarn_mscale(rope_scaling.factor,
+                       rope_scaling.mscale_all_dim) ** 2
+
+
 def mla_attention(params: dict, x: jnp.ndarray, positions: jnp.ndarray,
                   *, n_heads: int, kv_lora_rank: int,
                   qk_nope_head_dim: int = 128, qk_rope_head_dim: int = 64,
                   v_head_dim: int = 128, rope_theta: float = 10000.0,
+                  rope_scaling=None,
                   attn_impl=blockwise_attention) -> jnp.ndarray:
     """Full-sequence MLA.  The latent c_kv is shared across heads; the RoPE
     key part k_pe is computed once and broadcast (DeepSeek-V2 §2.1)."""
@@ -393,36 +413,42 @@ def mla_attention(params: dict, x: jnp.ndarray, positions: jnp.ndarray,
     qk_head = qk_nope_head_dim + qk_rope_head_dim
     q = (x @ params["wq"]).reshape(B, S, n_heads, qk_head)
     q_nope, q_pe = q[..., :qk_nope_head_dim], q[..., qk_nope_head_dim:]
-    dkv = x @ params["wdkv"]                               # (B,S,r+dr)
-    c_kv, k_pe = dkv[..., :kv_lora_rank], dkv[..., kv_lora_rank:]
+    c_kv, k_pe = _mla_latent(params, x, kv_lora_rank)
     k_pe = k_pe[:, :, None, :]                             # (B,S,1,dr)
-    q_pe, k_pe = apply_rope(q_pe, k_pe, positions, rope_theta)
+    q_pe, k_pe = apply_rope(q_pe, k_pe, positions, rope_theta, rope_scaling)
     k_nope, v = _mla_expand(params, c_kv, n_heads, qk_nope_head_dim,
                             v_head_dim)
     k_full = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:3]
                                   + (qk_rope_head_dim,))], axis=-1)
     q_full = jnp.concatenate([q_nope, q_pe], axis=-1)
+    # attn_impl scales by qk_head^-0.5; q carries YaRN's factor
+    factor = _yarn_softmax_factor(rope_scaling)
+    if factor != 1.0:
+        q_full = (q_full.astype(jnp.float32) * factor).astype(q_full.dtype)
     out = attn_impl(q_full, k_full, v, causal=True, window=None)
     return out.reshape(B, S, n_heads * v_head_dim) @ params["wo"]
 
 
+@jax.named_scope("mla.decode")
 def mla_decode_step(params: dict, x: jnp.ndarray, cache_c: jnp.ndarray,
                     cache_kpe: jnp.ndarray, cache_len: jnp.ndarray,
                     *, n_heads: int, kv_lora_rank: int,
                     qk_nope_head_dim: int = 128, qk_rope_head_dim: int = 64,
-                    v_head_dim: int = 128, rope_theta: float = 10000.0
+                    v_head_dim: int = 128, rope_theta: float = 10000.0,
+                    rope_scaling=None
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step with the COMPRESSED cache (the MLA memory win):
-    cache_c: (B, Smax, r) latents; cache_kpe: (B, Smax, dr)."""
+    cache_c: (B, Smax, r) normalised latents; cache_kpe: (B, Smax, dr)
+    rotated rope keys.  Its device ops carry the scope ``mla.decode``."""
     B = x.shape[0]
     qk_head = qk_nope_head_dim + qk_rope_head_dim
     q = (x @ params["wq"]).reshape(B, 1, n_heads, qk_head)
     q_nope, q_pe = q[..., :qk_nope_head_dim], q[..., qk_nope_head_dim:]
-    dkv = x @ params["wdkv"]
-    c_new, kpe_new = dkv[..., :kv_lora_rank], dkv[..., kv_lora_rank:]
+    c_new, kpe_new = _mla_latent(params, x, kv_lora_rank)
     pos = cache_len[:, None]
-    q_pe, kpe_rot = apply_rope(q_pe, kpe_new[:, :, None, :], pos, rope_theta)
+    q_pe, kpe_rot = apply_rope(q_pe, kpe_new[:, :, None, :], pos, rope_theta,
+                               rope_scaling)
     cache_c = jax.vmap(
         lambda c, n, i: jax.lax.dynamic_update_slice(
             c, n.astype(c.dtype), (i, 0))
@@ -432,12 +458,12 @@ def mla_decode_step(params: dict, x: jnp.ndarray, cache_c: jnp.ndarray,
             c, n.astype(c.dtype), (i, 0))
     )(cache_kpe, kpe_rot[:, :, 0, :], cache_len)
 
-    # absorbed-style scoring: expand latents (simple variant; the Pallas
-    # decode kernel implements the truly-absorbed matmul); fp8 caches are
-    # upcast to the compute dtype on read
+    # expand every cached latent to per-head K_nope and V (the simple form;
+    # the absorbed form scores q against the latent directly); fp8 caches
+    # are upcast to the compute dtype on read
     k_nope, v = _mla_expand(params, cache_c.astype(x.dtype), n_heads,
                             qk_nope_head_dim, v_head_dim)  # (B,Smax,H,*)
-    scale = 1.0 / math.sqrt(qk_head)
+    scale = _yarn_softmax_factor(rope_scaling) / math.sqrt(qk_head)
     s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bqhd,bkd->bhqk", q_pe,

@@ -49,11 +49,42 @@ def init_moe(rng, d_model: int, d_ff_expert: int, n_routed: int,
     return p
 
 
+def top_k_gates(logits: jnp.ndarray, top_k: int, norm_topk_prob: bool
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(gates, expert ids), each (..., top_k), from f32 router logits."""
+    if norm_topk_prob:
+        top_vals, top_idx = jax.lax.top_k(logits, top_k)
+        return jax.nn.softmax(top_vals, axis=-1), top_idx   # renormalized
+    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+
+
+def _route(params: dict, x: jnp.ndarray, top_k: int,
+           router_noise: Optional[jnp.ndarray],
+           norm_topk_prob: bool) -> jnp.ndarray:
+    """(B, S, E) combine weights: nonzero at each token's top-k experts."""
+    B, S, _ = x.shape
+    n_routed = params["router"].shape[1]
+    logits = (x.astype(jnp.float32) @ params["router"])     # (B,S,E)
+    if router_noise is not None:
+        logits = logits + router_noise
+    gates, top_idx = top_k_gates(logits, top_k, norm_topk_prob)
+    # dense dispatch mask: (B,S,E) combine weights
+    combine = jnp.zeros((B, S, n_routed), jnp.float32)
+    return jax.vmap(jax.vmap(
+        lambda c, i, g: c.at[i].add(g)))(combine, top_idx, gates)
+
+
 def moe_forward(params: dict, x: jnp.ndarray, top_k: int,
-                router_noise: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                router_noise: Optional[jnp.ndarray] = None, *,
+                norm_topk_prob: bool = True) -> jnp.ndarray:
     """x: (B, S, d_model) -> (B, S, d_model).
 
-    Routing weights are renormalized over the top-k (Mixtral convention).
+    Gates: with ``norm_topk_prob`` a softmax over the top-k logits
+    (Mixtral); without it a softmax over all experts in f32, whose top-k
+    probabilities are kept as they are (DeepSeek-V2, ``norm_topk_prob:
+    false``, ``routed_scaling_factor`` 1).  Device ops carry the scopes
+    ``moe.route`` (router and gates), ``moe.experts`` (routed experts) and
+    ``moe.shared`` (shared experts).
 
     Two dense-dispatch layouts (both exact; gathered EP dispatch lives in
     parallel/ep.py):
@@ -63,19 +94,23 @@ def moe_forward(params: dict, x: jnp.ndarray, top_k: int,
         16): one expert's (B,S,f) intermediate live at a time — the einsum
         layout would put the FULL (E,B,S,f) tensor on every device.
     """
+    with jax.named_scope("moe.route"):
+        combine = _route(params, x, top_k, router_noise, norm_topk_prob)
+    with jax.named_scope("moe.experts"):
+        out = _experts(params, x, combine)
+    if "shared" in params:
+        from .mlp import mlp_forward
+        with jax.named_scope("moe.shared"):
+            out = out + mlp_forward(params["shared"], x)
+    return out
+
+
+def _experts(params: dict, x: jnp.ndarray,
+             combine: jnp.ndarray) -> jnp.ndarray:
+    """Every routed expert on every token, weighted by ``combine``."""
     from .hints import mesh_axis_size
     B, S, d = x.shape
     n_routed = params["router"].shape[1]
-    logits = (x.astype(jnp.float32) @ params["router"])     # (B,S,E)
-    if router_noise is not None:
-        logits = logits + router_noise
-    top_vals, top_idx = jax.lax.top_k(logits, top_k)        # (B,S,k)
-    gates = jax.nn.softmax(top_vals, axis=-1)               # renormalized
-    # dense dispatch mask: (B,S,E) combine weights
-    combine = jnp.zeros((B, S, n_routed), jnp.float32)
-    combine = jax.vmap(jax.vmap(
-        lambda c, i, g: c.at[i].add(g)))(combine, top_idx, gates)
-
     m = mesh_axis_size("model")
     gated = "w_gate" in params
     if m > 1 and n_routed % m == 0:
@@ -149,7 +184,4 @@ def moe_forward(params: dict, x: jnp.ndarray, top_k: int,
         xs = ((params["w_up"], params["w_gate"], params["w_down"], comb_t)
               if gated else (params["w_up"], params["w_down"], comb_t))
         out, _ = jax.lax.scan(expert_step, jnp.zeros_like(x), xs)
-    if "shared" in params:
-        from .mlp import mlp_forward
-        out = out + mlp_forward(params["shared"], x)
     return out

@@ -1,4 +1,14 @@
-"""Rotary position embeddings: standard RoPE and Qwen2-VL's M-RoPE.
+"""Rotary position embeddings: standard RoPE, YaRN-scaled RoPE
+(DeepSeek-V2) and Qwen2-VL's M-RoPE.
+
+YaRN (arXiv:2309.00071), as HF ``DeepseekV2YarnRotaryEmbedding`` computes
+it: frequency slots that turn fewer than ``beta_slow`` times over the
+original context are divided by ``factor``, slots that turn more than
+``beta_fast`` times are kept, and a linear ramp blends the slots between;
+cos and sin are scaled by ``mscale(factor, mscale) / mscale(factor,
+mscale_all_dim)``.  The attention layer multiplies its softmax scale by
+``mscale(factor, mscale_all_dim) ** 2``.  ``scaling`` is a
+``models.config.RopeScaling``; without it the tables are plain RoPE's.
 
 M-RoPE (arXiv:2409.12191) splits the head dimension into three sections
 rotated by (temporal, height, width) position ids.  The vision frontend is
@@ -9,18 +19,44 @@ to standard RoPE — the property tests rely on this identity).
 
 from __future__ import annotations
 
+import math
 from typing import Sequence, Tuple
 
 import jax.numpy as jnp
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(head_dim: int, theta: float,
+                          scaling) -> Tuple[int, int]:
+    """The first and last frequency slots of YaRN's ramp."""
+    def slot(rotations: float) -> float:
+        return head_dim * math.log(
+            scaling.original_max_position_embeddings
+            / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(slot(scaling.beta_fast)), 0),
+            min(math.ceil(slot(scaling.beta_slow)), head_dim - 1))
+
+
 def rope_angles(positions: jnp.ndarray, head_dim: int,
-                theta: float = 10000.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                theta: float = 10000.0,
+                scaling=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(cos, sin) tables of shape positions.shape + (head_dim // 2,)."""
     half = head_dim // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is not None:
+        low, high = yarn_correction_range(head_dim, theta, scaling)
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        freqs = freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    if scaling is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    m = yarn_mscale(scaling.factor, scaling.mscale) \
+        / yarn_mscale(scaling.factor, scaling.mscale_all_dim)
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def _rotate(x: jnp.ndarray, cos: jnp.ndarray,
@@ -37,10 +73,11 @@ def _rotate(x: jnp.ndarray, cos: jnp.ndarray,
 
 
 def apply_rope(q: jnp.ndarray, k: jnp.ndarray, positions: jnp.ndarray,
-               theta: float = 10000.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Standard RoPE.  q: (B, S, Hq, D), k: (B, S, Hk, D),
-    positions: (B, S) absolute token positions."""
-    cos, sin = rope_angles(positions, q.shape[-1], theta)
+               theta: float = 10000.0,
+               scaling=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """RoPE, YaRN-scaled when ``scaling`` is given.  q: (B, S, Hq, D),
+    k: (B, S, Hk, D), positions: (B, S) absolute token positions."""
+    cos, sin = rope_angles(positions, q.shape[-1], theta, scaling)
     return _rotate(q, cos, sin), _rotate(k, cos, sin)
 
 

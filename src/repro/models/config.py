@@ -34,6 +34,18 @@ class EncoderConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling, as DeepSeek-V2's ``rope_scaling`` (type "yarn")
+    states it; the equations are in ``layers/rope.py``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     d_model: int
@@ -48,6 +60,7 @@ class ModelConfig:
     qkv_bias: bool = False
     rope: str = "rope"                 # "rope" | "mrope" | "none"
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None     # YaRN (MLA only)
     attn_kind: str = "gqa"             # "gqa" | "mla"
     # MLA dims (DeepSeek-V2)
     kv_lora_rank: int = 512
@@ -63,6 +76,10 @@ class ModelConfig:
     top_k: int = 0
     n_shared: int = 0
     d_ff_expert: int = 0
+    # True: softmax over the top-k logits (Mixtral).  False: softmax over
+    # all experts, top-k of those probabilities kept as they are
+    # (DeepSeek-V2).
+    norm_topk_prob: bool = True
     first_k_dense: int = 0             # DeepSeek: first k layers use dense FFN
     d_ff_dense_first: int = 0
     # SSM (Mamba2)
@@ -109,6 +126,8 @@ class ModelConfig:
                                            for s in self.block_pattern):
             if self.n_heads % self.n_kv_heads:
                 raise ValueError("n_heads must divide by n_kv_heads")
+        if self.rope_scaling is not None and self.attn_kind != "mla":
+            raise ValueError("rope_scaling (YaRN) is implemented for MLA only")
         if self.ffn_kind == "moe" and (not self.n_routed or not self.top_k):
             raise ValueError("moe config incomplete")
         if any(s.kind == "ssm" for s in self.block_pattern):

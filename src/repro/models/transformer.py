@@ -146,7 +146,7 @@ def param_count(params) -> int:
 
 def _ffn_apply(cfg: ModelConfig, p: dict, x: jnp.ndarray) -> jnp.ndarray:
     if "router" in p:          # MoE params
-        return moe_forward(p, x, cfg.top_k)
+        return moe_forward(p, x, cfg.top_k, norm_topk_prob=cfg.norm_topk_prob)
     return mlp_forward(p, x)
 
 
@@ -177,7 +177,8 @@ def _layer_apply(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
                              qk_nope_head_dim=cfg.qk_nope_head_dim,
                              qk_rope_head_dim=cfg.qk_rope_head_dim,
                              v_head_dim=cfg.v_head_dim,
-                             rope_theta=cfg.rope_theta)
+                             rope_theta=cfg.rope_theta,
+                             rope_scaling=cfg.rope_scaling)
     else:
         attn = gqa_attention(p["attn"], h, positions,
                              n_heads=cfg.n_heads,
@@ -380,7 +381,8 @@ def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
                                     qk_nope_head_dim=cfg.qk_nope_head_dim,
                                     qk_rope_head_dim=cfg.qk_rope_head_dim,
                                     v_head_dim=cfg.v_head_dim,
-                                    rope_theta=cfg.rope_theta)
+                                    rope_theta=cfg.rope_theta,
+                                    rope_scaling=cfg.rope_scaling)
         new_lc["c_kv"], new_lc["k_pe"] = cc, ck
     else:
         # sliding-window caches are ring buffers (see gqa_decode_step)

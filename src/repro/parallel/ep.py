@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 
+from repro.layers.moe import top_k_gates
+
 
 def _bucket_by_expert(x, idx, n_exp: int, cap: int):
     """Bucket token-assignments into (n_exp, cap, d) buffers, dropping
@@ -57,9 +59,11 @@ def _bucket_by_expert(x, idx, n_exp: int, cap: int):
 
 
 def moe_ep_forward(params: dict, x: jnp.ndarray, top_k: int, mesh: Mesh,
-                   axis: str = "model", cap_factor: float = 1.25
+                   axis: str = "model", cap_factor: float = 1.25, *,
+                   norm_topk_prob: bool = True
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """EP MoE over ``axis``.  x: (B, S, d); S must divide mesh[axis].
+    Gates follow ``layers.moe.top_k_gates``, as ``moe_forward``'s do.
     Returns (y (B,S,d), dropped_fraction scalar)."""
     n_exp = params["w_up"].shape[0]
     tp = mesh.shape[axis]
@@ -77,8 +81,7 @@ def moe_ep_forward(params: dict, x: jnp.ndarray, top_k: int, mesh: Mesh,
         T = Bl * Sl
         xt = x_l.reshape(T, d)
         logits = xt.astype(jnp.float32) @ router           # router replicated
-        top_vals, top_idx = jax.lax.top_k(logits, top_k)
-        gates = jax.nn.softmax(top_vals, axis=-1)
+        gates, top_idx = top_k_gates(logits, top_k, norm_topk_prob)
         cap = max(1, int(cap_factor * T * top_k / n_exp))
         buffers, (tok_a, e_idx, s_idx, kept), drops = _bucket_by_expert(
             xt, top_idx, n_exp, cap)

@@ -162,20 +162,16 @@ class ServingEngine:
         s = self.slots[i]
         tr = self.trace
         with OFF if tr is None else tr.span("engine.prefill.sync"):
-            lens = np.array(jax.device_get(self.cache["len"]))
-            lens[i] = 0
-            self.cache["len"] = jnp.asarray(lens)
+            self.cache["len"] = self._lengths(replaying=(i, 0))
         for t in range(len(s.prompt)):
             toks = np.zeros((self.max_batch, 1), np.int32)
             toks[i, 0] = s.prompt[t]
             logits_tok, logits, cache = self._decode(
                 self.params, jnp.asarray(toks), self.cache)
             with OFF if tr is None else tr.span("engine.prefill.sync"):
-                # only slot i's length may advance
-                new_len = np.array(jax.device_get(cache["len"]))
-                keep = np.array(jax.device_get(self.cache["len"]))
-                keep[i] = new_len[i]
-                cache["len"] = jnp.asarray(keep)
+                # only slot i's length may advance; one step at a time
+                cache["len"] = self._lengths(replaying=(i, t + 1))
+                logits_tok.block_until_ready()
             self.cache = cache
         s.generated = 1
         with OFF if tr is None else tr.span("engine.prefill.sync"):
@@ -247,14 +243,6 @@ class ServingEngine:
             nxt, _, cache = self._decode(self.params, jnp.asarray(toks),
                                          self.cache)
             nxt = np.array(jax.device_get(nxt))
-        with OFF if tr is None else tr.span("engine.decode.sync"):
-            # inactive slots must not advance their length counters
-            new_len = np.array(jax.device_get(cache["len"]))
-            old_len = np.array(jax.device_get(self.cache["len"]))
-            mask = np.zeros(self.max_batch, bool)
-            mask[active] = True
-            new_len = np.where(mask, new_len, old_len)
-            cache["len"] = jnp.asarray(new_len)
         self.cache = cache
         now += time.perf_counter() - t0
 
@@ -274,4 +262,19 @@ class ServingEngine:
         # KV budget enforcement (greedy batching can overshoot)
         while self._kv_used() > self.kv_budget:
             self._evict_most_recent()
+        with OFF if tr is None else tr.span("engine.decode.sync"):
+            # the step advanced every slot; an idle one must not grow
+            if not all(s.active for s in self.slots):
+                self.cache["len"] = self._lengths()
         return now
+
+    def _lengths(self, replaying=None) -> jnp.ndarray:
+        """Every slot's cache length as the host knows it, uploaded: an
+        active slot holds its prompt and each generated token but the last
+        (not yet fed), an idle one 0; ``replaying`` = (slot, tokens fed)
+        for a slot whose prompt is being replayed."""
+        lens = np.array([s.kv_tokens - 1 if s.active else 0
+                         for s in self.slots], np.int32)
+        if replaying is not None:
+            lens[replaying[0]] = replaying[1]
+        return jnp.asarray(lens)

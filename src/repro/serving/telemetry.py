@@ -22,18 +22,42 @@ reads them goes on reading the same quantity.
                          ``waited_s`` (engine clock at admission minus the
                          request's arrival)
 ``engine.prefill.sync``  the replay's host syncs: the length reset, the
-                         per-token length fix-up, the first token and
-                         logits fetch
+                         per-token length fix-up (an upload of the
+                         lengths the host keeps) and wait on the step,
+                         the first token and logits fetch
 ``engine.decode``        one decode iteration; ``active`` slots,
                          ``kv_tokens`` in use over all slots,
                          ``kv_reserved`` (``max_batch * max_len``)
 ``engine.decode.call``   the step call and its tokens' fetch: the host
                          waiting on the device
-``engine.decode.sync``   the length fix-up: two fetches, one upload
+``engine.decode.sync``   the length fix-up: one upload of the lengths
+                         the host keeps, when a slot is idle (a full
+                         batch needs none)
 ``engine.evict``         one preemption
 =======================  ===================================================
 
 Each span and attr has a reader: ``summary`` here, an operator's line.
+
+Inside the jitted step, the MLA and MoE layers put their device ops under
+``jax.named_scope`` scopes.  The compiled step carries the scope in each
+instruction's ``op_name`` metadata (``.../<scope>/...``); a profiler
+trace names the instruction, so a device op's scope is read from the
+compiled step.  They are an interface too, kept under these names by a
+later rebuild of the layer:
+
+=================  =========================================================
+``mla.decode``     ``layers/attention.py`` ``mla_decode_step``: q and latent
+                   projections, the latent and rope-key cache writes, the
+                   expansion of the cached latents to per-head K/V, the
+                   scores, the softmax and the readout through ``wo``
+``moe.route``      ``layers/moe.py``: router logits, the gates and the
+                   (B, S, E) combine weights
+``moe.experts``    ``layers/moe.py``: the routed experts, weighted by their
+                   gates
+``moe.shared``     ``layers/moe.py``: the shared experts on every token
+=================  =========================================================
+
+No reader in the repository reads them yet; ``summary()`` does not.
 """
 
 from __future__ import annotations

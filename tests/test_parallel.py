@@ -31,8 +31,9 @@ print("pipeline OK")
 """, devices=8)
 
 
-def test_ep_matches_dense_oracle(subproc):
-    subproc("""
+@pytest.mark.parametrize("norm_topk_prob", [True, False])
+def test_ep_matches_dense_oracle(subproc, norm_topk_prob):
+    subproc(f"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh
 from repro.layers.moe import init_moe, moe_forward
@@ -45,9 +46,10 @@ d, f, E, k = 16, 32, 8, 2
 params = init_moe(rng, d, f, E, k, dtype=jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, d), jnp.float32)
 
-dense = moe_forward(params, x, k)
-ep, drop = moe_ep_forward(params, x, k, mesh, cap_factor=8.0)
-assert float(drop) == 0.0, f"unexpected drops: {float(drop)}"
+dense = moe_forward(params, x, k, norm_topk_prob={norm_topk_prob})
+ep, drop = moe_ep_forward(params, x, k, mesh, cap_factor=8.0,
+                          norm_topk_prob={norm_topk_prob})
+assert float(drop) == 0.0, f"unexpected drops: {{float(drop)}}"
 np.testing.assert_allclose(np.asarray(ep), np.asarray(dense), rtol=2e-4,
                            atol=2e-4)
 print("ep OK")

@@ -1,6 +1,7 @@
 """Real serving engine: completion, preemption, routing, fidelity hooks."""
 
 import jax
+import numpy as np
 import pytest
 
 from repro import configs as C
@@ -67,6 +68,33 @@ def test_kv_budget_preemption(small):
     rep = eng.run(_reqs(cfg, 4, gen=8, ctx=16), time_scale=0.0)
     assert len(rep.results) == 4           # everyone completes eventually
     assert rep.preemptions >= 0
+
+
+@pytest.mark.parametrize("budget", [None, 40], ids=["plain", "preempting"])
+def test_step_reads_each_slots_own_length(small, budget):
+    """The engine keeps the cache lengths on the host and fetches none:
+    before every step the cache holds, for each slot that has served its
+    first token, its prompt and every generated token but the last, and 0
+    for an idle slot, through admissions, finishes and preemptions."""
+    cfg, params = small
+    eng = ServingEngine(cfg, params, max_batch=3, max_len=64,
+                        kv_token_budget=budget)
+    step, steps = eng._decode, []
+
+    def decode(p, toks, cache):
+        lens = np.asarray(cache["len"])
+        for i, s in enumerate(eng.slots):
+            if not s.active:
+                assert lens[i] == 0
+            elif s.generated:
+                assert lens[i] == s.kv_tokens - 1
+        steps.append(lens)
+        return step(p, toks, cache)
+
+    eng._decode = decode
+    rep = eng.run(_reqs(cfg, 5, gen=8, ctx=16), time_scale=0.0)
+    assert len(rep.results) == 5 and len(steps) > 5 * 8
+    assert (budget is None) == (rep.preemptions == 0)
 
 
 def test_router_spreads_load(small):
