@@ -7,7 +7,11 @@ Two execution paths:
     S x S score matrix is never materialized — mandatory for the 32k-prefill
     dry-run shapes, and the same tiling the Pallas kernel
     (repro/kernels/flash_attention) implements in VMEM.
-  * ``*_decode_step`` — one new token against a KV cache (serving).
+  * ``*_decode_step`` — one new token against a KV cache (serving).  A
+    step takes one layer's cache, or the stacked caches of the decoder's
+    layer scan and the layer to use: it writes one entry per slot into the
+    stacked leaf and reads the layer where it lies, so a carried, donated
+    cache is updated in place and never copied.
 
 Parameters are plain dicts of jnp arrays; init fns take explicit dims.
 """
@@ -304,23 +308,45 @@ def gqa_attention(params: dict, x: jnp.ndarray, positions: jnp.ndarray,
     return out.reshape(B, S, n_heads * head_dim) @ params["wo"]
 
 
+def cache_layer(leaf: jnp.ndarray, layer) -> jnp.ndarray:
+    """Layer ``layer`` of a stacked (R, ...) cache leaf, indexed where it
+    lies; an unstacked leaf (``layer`` None) as it is."""
+    if layer is None:
+        return leaf
+    return jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=False)
+
+
+def write_entries(cache: jnp.ndarray, new: jnp.ndarray, idx: jnp.ndarray,
+                  layer=None) -> jnp.ndarray:
+    """Write each slot's entry ``new[b]`` (B, 1, ...) at position ``idx[b]``
+    of ``cache`` (B, Smax, ...), or of layer ``layer`` of a stacked cache
+    (R, B, Smax, ...): one scatter of B entries, in place when the cache is
+    carried or donated.  A position past the end clamps to the last, as
+    ``dynamic_update_slice`` does."""
+    b = jnp.arange(new.shape[0])
+    at = (b, idx) if layer is None else (layer, b, idx)
+    return cache.at[at].set(new[:, 0].astype(cache.dtype), mode="clip")
+
+
 def gqa_decode_step(params: dict, x: jnp.ndarray, cache_k: jnp.ndarray,
                     cache_v: jnp.ndarray, cache_len: jnp.ndarray,
                     *, n_heads: int, n_kv_heads: int, head_dim: int,
                     window: Optional[int] = None, rope: str = "rope",
-                    rope_theta: float = 10000.0
+                    rope_theta: float = 10000.0, layer=None
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One decode step.  x: (B, 1, d_model); cache_k/v: (B, Smax, Hkv, D);
-    cache_len: (B,) ABSOLUTE sequence lengths so far.
+    """One decode step.  x: (B, 1, d_model); cache_k/v: (B, Smax, Hkv, D),
+    or with ``layer`` (a scalar index) the stacked (R, B, Smax, Hkv, D)
+    caches of a layer scan, whose layer ``layer`` the step writes and
+    reads in place; cache_len: (B,) ABSOLUTE sequence lengths so far.
 
     Sliding-window layers use a RING cache: allocate Smax == window + 1 and
     the ring then holds exactly the last `window`+1 tokens — K entries are
     RoPE-rotated at their absolute positions when written, attention scores
     need no position bookkeeping, and no further window mask is required.
     Full-attention layers use Smax == max_len (linear writes).
-    Returns (y, new_k, new_v)."""
+    Returns (y, new_k, new_v), the caches in the layout they came in."""
     B = x.shape[0]
-    Smax = cache_k.shape[1]
+    Smax = cache_k.shape[-3]
     q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
     pos = cache_len[:, None]                               # (B, 1) absolute
     if rope == "rope":
@@ -333,14 +359,8 @@ def gqa_decode_step(params: dict, x: jnp.ndarray, cache_k: jnp.ndarray,
     # is a ring. The ring retains the last Smax-1 >= window tokens.
     ring = window is not None and Smax <= window + 16
     idx = cache_len % Smax if ring else cache_len          # (B,) write slot
-    cache_k = jax.vmap(
-        lambda c, kk, i: jax.lax.dynamic_update_slice(
-            c, kk.astype(c.dtype), (i, 0, 0))
-    )(cache_k, k, idx)
-    cache_v = jax.vmap(
-        lambda c, vv, i: jax.lax.dynamic_update_slice(
-            c, vv.astype(c.dtype), (i, 0, 0))
-    )(cache_v, v, idx)
+    cache_k = write_entries(cache_k, k, idx, layer)
+    cache_v = write_entries(cache_v, v, idx, layer)
 
     # Grouped by KV head: query head h attends with KV head h // g, so q is
     # viewed as (B, 1, Hkv, g, D) and each cached K/V entry is read once —
@@ -355,8 +375,10 @@ def gqa_decode_step(params: dict, x: jnp.ndarray, cache_k: jnp.ndarray,
     g = n_heads // n_kv_heads
     scale = 1.0 / math.sqrt(head_dim)
     qg = q.reshape(B, 1, n_kv_heads, g, head_dim)
-    kc = shard_hint(cache_k.astype(q.dtype), daxes, "model", None, None)
-    vc = shard_hint(cache_v.astype(q.dtype), daxes, "model", None, None)
+    kc = shard_hint(cache_layer(cache_k, layer).astype(q.dtype), daxes,
+                    "model", None, None)
+    vc = shard_hint(cache_layer(cache_v, layer).astype(q.dtype), daxes,
+                    "model", None, None)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kc,
                    preferred_element_type=jnp.float32) * scale
     s = shard_hint(s, daxes, None, None, None, "model")
@@ -436,11 +458,13 @@ def mla_decode_step(params: dict, x: jnp.ndarray, cache_c: jnp.ndarray,
                     *, n_heads: int, kv_lora_rank: int,
                     qk_nope_head_dim: int = 128, qk_rope_head_dim: int = 64,
                     v_head_dim: int = 128, rope_theta: float = 10000.0,
-                    rope_scaling=None
+                    rope_scaling=None, layer=None
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step with the COMPRESSED cache (the MLA memory win):
     cache_c: (B, Smax, r) normalised latents; cache_kpe: (B, Smax, dr)
-    rotated rope keys.  Its device ops carry the scope ``mla.decode``."""
+    rotated rope keys; with ``layer``, the stacked (R, B, Smax, *) caches
+    of a layer scan, written and read in place as in ``gqa_decode_step``.
+    Its device ops carry the scope ``mla.decode``."""
     B = x.shape[0]
     qk_head = qk_nope_head_dim + qk_rope_head_dim
     q = (x @ params["wq"]).reshape(B, 1, n_heads, qk_head)
@@ -449,27 +473,24 @@ def mla_decode_step(params: dict, x: jnp.ndarray, cache_c: jnp.ndarray,
     pos = cache_len[:, None]
     q_pe, kpe_rot = apply_rope(q_pe, kpe_new[:, :, None, :], pos, rope_theta,
                                rope_scaling)
-    cache_c = jax.vmap(
-        lambda c, n, i: jax.lax.dynamic_update_slice(
-            c, n.astype(c.dtype), (i, 0))
-    )(cache_c, c_new, cache_len)
-    cache_kpe = jax.vmap(
-        lambda c, n, i: jax.lax.dynamic_update_slice(
-            c, n.astype(c.dtype), (i, 0))
-    )(cache_kpe, kpe_rot[:, :, 0, :], cache_len)
+    cache_c = write_entries(cache_c, c_new, cache_len, layer)
+    cache_kpe = write_entries(cache_kpe, kpe_rot[:, :, 0, :], cache_len,
+                              layer)
 
     # expand every cached latent to per-head K_nope and V (the simple form;
     # the absorbed form scores q against the latent directly); fp8 caches
     # are upcast to the compute dtype on read
-    k_nope, v = _mla_expand(params, cache_c.astype(x.dtype), n_heads,
-                            qk_nope_head_dim, v_head_dim)  # (B,Smax,H,*)
+    k_nope, v = _mla_expand(params,
+                            cache_layer(cache_c, layer).astype(x.dtype),
+                            n_heads, qk_nope_head_dim,
+                            v_head_dim)                    # (B,Smax,H,*)
     scale = _yarn_softmax_factor(rope_scaling) / math.sqrt(qk_head)
     s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bqhd,bkd->bhqk", q_pe,
-                      cache_kpe.astype(x.dtype),
+                      cache_layer(cache_kpe, layer).astype(x.dtype),
                       preferred_element_type=jnp.float32)) * scale
-    Smax = cache_c.shape[1]
+    Smax = cache_c.shape[-2]
     valid = jnp.arange(Smax)[None, :] <= cache_len[:, None]
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)

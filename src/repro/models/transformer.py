@@ -12,6 +12,12 @@ Design notes
   population) and ``decode_step`` (one token vs. cache) are the entire
   public surface, shared by the trainer, the serving engine, and the
   multi-pod dry-run.
+* **The decode step carries the cache**: the layer scan iterates over the
+  stacked parameters and the layer index, and carries the stacked cache;
+  each layer writes its new entries (one per slot) or its new SSM state
+  into the stacked leaves in place and reads its layer where it lies.  No
+  layer's cache is sliced out or restacked, so a donated cache is updated
+  in place.
 * **Heterogeneous blocks**: the block pattern interleaves attention and SSD
   layers (Gemma3 local:global, Zamba2 hybrid); Zamba2's shared attention
   block has ONE weight set applied once per repeat (weights live outside
@@ -33,7 +39,7 @@ from repro.layers import (gqa_attention, gqa_decode_step, init_attention,
                           mamba2_decode_step, mamba2_forward, mla_attention,
                           mla_decode_step, mlp_forward, moe_forward,
                           rms_norm)
-from repro.layers.attention import blockwise_attention
+from repro.layers.attention import blockwise_attention, cache_layer
 from .config import LayerSpec, ModelConfig
 
 
@@ -359,19 +365,35 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 # decode step (serving)
 # ---------------------------------------------------------------------------
 
+def _put_layer(leaf: jnp.ndarray, new: jnp.ndarray, layer) -> jnp.ndarray:
+    """``new`` as layer ``layer`` of the stacked leaf (in place), or as the
+    unstacked leaf itself (``layer`` None), in the leaf's dtype."""
+    new = new.astype(leaf.dtype)
+    if layer is None:
+        return new
+    return jax.lax.dynamic_update_index_in_dim(leaf, new, layer, 0)
+
+
 def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
-                  lc: dict, cache_len: jnp.ndarray) -> Tuple[jnp.ndarray,
-                                                             dict]:
+                  lc: dict, cache_len: jnp.ndarray,
+                  layer=None) -> Tuple[jnp.ndarray, dict]:
+    """One layer of the decode step.  ``lc`` holds the layer's cache leaves,
+    or with ``layer`` the layer scan's stacked leaves, of which layer
+    ``layer`` is read and written in place: attention K/V and MLA's latent
+    and rope key take one entry per slot, SSM leaves the layer's whole new
+    state; cross-attention K/V are read only."""
     new_lc = dict(lc)
+    at = functools.partial(cache_layer, layer=layer)
     if spec.kind == "ssm":
         h = rms_norm(x, p["norm1"])
         y, st, cv = mamba2_decode_step(
-            p["mixer"], h, lc["ssm"],
-            {"x": lc["conv_x"], "bc": lc["conv_bc"]},
+            p["mixer"], h, at(lc["ssm"]),
+            {"x": at(lc["conv_x"]), "bc": at(lc["conv_bc"])},
             d_inner=cfg.d_inner, d_state=cfg.d_state,
             n_heads=cfg.n_ssd_heads, n_groups=cfg.n_ssm_groups)
-        new_lc["ssm"] = st
-        new_lc["conv_x"], new_lc["conv_bc"] = cv["x"], cv["bc"]
+        new_lc["ssm"] = _put_layer(lc["ssm"], st, layer)
+        new_lc["conv_x"] = _put_layer(lc["conv_x"], cv["x"], layer)
+        new_lc["conv_bc"] = _put_layer(lc["conv_bc"], cv["bc"], layer)
         return x + y, new_lc
     h = rms_norm(x, p["norm1"])
     if cfg.attn_kind == "mla":
@@ -382,7 +404,8 @@ def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
                                     qk_rope_head_dim=cfg.qk_rope_head_dim,
                                     v_head_dim=cfg.v_head_dim,
                                     rope_theta=cfg.rope_theta,
-                                    rope_scaling=cfg.rope_scaling)
+                                    rope_scaling=cfg.rope_scaling,
+                                    layer=layer)
         new_lc["c_kv"], new_lc["k_pe"] = cc, ck
     else:
         # sliding-window caches are ring buffers (see gqa_decode_step)
@@ -390,7 +413,7 @@ def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
             p["attn"], h, lc["k"], lc["v"], cache_len,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.resolved_head_dim, window=spec.window,
-            rope=cfg.rope, rope_theta=cfg.rope_theta)
+            rope=cfg.rope, rope_theta=cfg.rope_theta, layer=layer)
         new_lc["k"], new_lc["v"] = ck, cv
     x = x + y
     if cfg.cross_attn and "xk" in lc:
@@ -399,8 +422,8 @@ def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
         hd = cfg.resolved_head_dim
         rep = cfg.n_heads // cfg.n_kv_heads
         q = (hx @ p["xattn"]["wq"]).reshape(B, 1, cfg.n_heads, hd)
-        kr = jnp.repeat(lc["xk"], rep, axis=2)
-        vr = jnp.repeat(lc["xv"], rep, axis=2)
+        kr = jnp.repeat(at(lc["xk"]), rep, axis=2)
+        vr = jnp.repeat(at(lc["xv"]), rep, axis=2)
         s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
                        preferred_element_type=jnp.float32) \
             / math.sqrt(hd)
@@ -417,7 +440,14 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                 embeds: Optional[jnp.ndarray] = None
                 ) -> Tuple[jnp.ndarray, dict]:
     """One serving step: (B, 1) token ids (or embeds) + cache -> logits
-    (B, vocab), updated cache."""
+    (B, vocab), updated cache.
+
+    The layer scan carries the stacked cache and writes each layer's new
+    entries into it in place (one per slot and leaf; an SSM layer's new
+    state); no layer's cache is copied out or restacked.  The serving
+    engine donates the cache to the jitted step (``serving.engine.
+    make_decode_step``), so the step updates the one cache buffer it is
+    given and the caller's cache is consumed."""
     if embeds is None:
         x = params["embed"][tokens]
     else:
@@ -435,40 +465,32 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             new_cache["prefix"].append(npc)
 
     shared = params.get("shared")
-    shared_cache = cache.get("shared")
 
     def block_body(carry, inp):
-        x = carry
-        if shared is not None:
-            blk, cblk, sck, scv = inp
-        else:
-            blk, cblk = inp
-        ncblk = {}
+        x, cblk, sc = carry
+        blk, layer = inp
+        cblk = dict(cblk)
         for i, spec in enumerate(cfg.block_pattern):
-            x, ncblk[f"l{i}"] = _layer_decode(cfg, spec, blk[f"l{i}"], x,
-                                              cblk[f"l{i}"], cache_len)
+            x, cblk[f"l{i}"] = _layer_decode(cfg, spec, blk[f"l{i}"], x,
+                                             cblk[f"l{i}"], cache_len, layer)
         if shared is not None:
             h = rms_norm(x, shared["norm1"])
             y, nk, nv = gqa_decode_step(
-                shared["attn"], h, sck, scv, cache_len,
+                shared["attn"], h, sc["k"], sc["v"], cache_len,
                 n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
                 head_dim=cfg.resolved_head_dim, rope=cfg.rope,
-                rope_theta=cfg.rope_theta)
+                rope_theta=cfg.rope_theta, layer=layer)
+            sc = {"k": nk, "v": nv}
             x = x + y
             x = x + mlp_forward(shared["mlp"], rms_norm(x, shared["norm2"]))
-            return x, (ncblk, nk, nv)
-        return x, ncblk
+        return (x, cblk, sc), None
 
-    if shared is not None:
-        xs = (params["blocks"], cache["blocks"], shared_cache["k"],
-              shared_cache["v"])
-        x, (ncb, nk, nv) = jax.lax.scan(block_body, x, xs)
-        new_cache["blocks"] = ncb
-        new_cache["shared"] = {"k": nk, "v": nv}
-    else:
-        x, ncb = jax.lax.scan(block_body, x, (params["blocks"],
-                                              cache["blocks"]))
-        new_cache["blocks"] = ncb
+    n_scan = jax.tree.leaves(params["blocks"])[0].shape[0]
+    (x, new_cache["blocks"], shared_cache), _ = jax.lax.scan(
+        block_body, (x, cache["blocks"], cache.get("shared")),
+        (params["blocks"], jnp.arange(n_scan)))
+    if shared_cache is not None:
+        new_cache["shared"] = shared_cache
 
     x = rms_norm(x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["head"]

@@ -10,6 +10,10 @@ Implements iteration-level batching over a slot-based KV cache:
   * each engine iteration runs ONE jitted decode step over all slots
     (inactive slots are masked); prefill populates a request's slot via the
     token-replay prefill;
+  * the step is given the cache to consume (``make_decode_step`` donates
+    it): it writes each slot's new entry in place and returns the one
+    cache buffer, so the engine holds the cache once and drops its
+    reference to the old one as soon as a step returns;
   * arrivals are honored in VIRTUAL time: the clock advances by measured
     step wall-times, and a request joins the queue once the virtual clock
     passes its arrival stamp.  This makes CPU-scale fidelity runs directly
@@ -36,6 +40,18 @@ import numpy as np
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.serving.telemetry import OFF, EngineTrace
+
+
+def make_decode_step(cfg: ModelConfig):
+    """The engine's jitted step: (params, tokens (B, 1), cache) ->
+    (greedy next tokens (B,), logits (B, vocab), new cache).  The cache is
+    donated: the step updates it in place, and the cache passed in is
+    deleted by the call."""
+    def _step(p, t, c):
+        logits, c2 = T.decode_step(p, cfg, t, c)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, c2
+
+    return jax.jit(_step, donate_argnums=(2,))
 
 
 @dataclasses.dataclass
@@ -84,7 +100,11 @@ class EngineReport:
 class ServingEngine:
     """Each ``RequestResult`` carries the request's first-token logits as
     served — what a correctness check compares against a reference forward
-    pass.  ``trace``, when given, records the engine's spans."""
+    pass.  ``trace``, when given, records the engine's spans.
+
+    ``self.cache`` is the one live cache: every step consumes it (donated,
+    see ``make_decode_step``) and the engine takes the step's output in its
+    place before anything else runs."""
 
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 4,
                  max_len: int = 512, kv_token_budget: Optional[int] = None,
@@ -100,11 +120,7 @@ class ServingEngine:
         self.queue: List[dict] = []
         self._order = 0
         self.preemptions = 0
-        def _step(p, t, c):
-            logits, c2 = T.decode_step(p, cfg, t, c)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, c2
-
-        self._decode = jax.jit(_step)
+        self._decode = make_decode_step(cfg)
 
     # -- fault tolerance -------------------------------------------------------
 
@@ -166,13 +182,12 @@ class ServingEngine:
         for t in range(len(s.prompt)):
             toks = np.zeros((self.max_batch, 1), np.int32)
             toks[i, 0] = s.prompt[t]
-            logits_tok, logits, cache = self._decode(
+            logits_tok, logits, self.cache = self._decode(
                 self.params, jnp.asarray(toks), self.cache)
             with OFF if tr is None else tr.span("engine.prefill.sync"):
                 # only slot i's length may advance; one step at a time
-                cache["len"] = self._lengths(replaying=(i, t + 1))
+                self.cache["len"] = self._lengths(replaying=(i, t + 1))
                 logits_tok.block_until_ready()
-            self.cache = cache
         s.generated = 1
         with OFF if tr is None else tr.span("engine.prefill.sync"):
             first = int(jax.device_get(logits_tok)[i])
@@ -240,10 +255,9 @@ class ServingEngine:
         for i in active:
             toks[i, 0] = self.slots[i].tokens[-1]
         with OFF if tr is None else tr.span("engine.decode.call"):
-            nxt, _, cache = self._decode(self.params, jnp.asarray(toks),
-                                         self.cache)
+            nxt, _, self.cache = self._decode(self.params,
+                                              jnp.asarray(toks), self.cache)
             nxt = np.array(jax.device_get(nxt))
-        self.cache = cache
         now += time.perf_counter() - t0
 
         for i in active:

@@ -13,9 +13,10 @@ With no recorder (``trace=None``, the default) a span site costs one
 owns the recorder and drops it when done.
 
 The names and attrs are an interface.  A later change that rebuilds a
-layer (a batched prefill, a donated cache, fewer host syncs) keeps
-emitting its span under the same name with the same attrs, so that what
-reads them goes on reading the same quantity.
+layer (a batched prefill, fewer host syncs) keeps emitting its span under
+the same name with the same attrs, so that what reads them goes on
+reading the same quantity.  The donated cache, which the step now writes
+in place (``engine.make_decode_step``), kept every span name and attr.
 
 =======================  ===================================================
 ``engine.prefill``       one request's prompt replay; ``tokens`` replayed,

@@ -73,8 +73,8 @@ def _served(cfg, params, requests, max_len=32):
             replaying.pop()
 
     def decode(p, toks, cache):
+        lens = np.array(cache["len"])       # the step consumes the cache
         nxt, logits, new = step(p, toks, cache)
-        lens = np.asarray(cache["len"])
         slots = replaying[-1:] or [i for i, s in enumerate(eng.slots)
                                    if s.active]
         for i in slots:

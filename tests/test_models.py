@@ -1,14 +1,19 @@
 """Per-architecture smoke tests: reduced configs, one forward + one train
 step on CPU, asserting output shapes and finiteness; decode consistency."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import configs as C
 from repro.launch.steps import make_train_step
-from repro.models import (decode_step, encdec_forward, forward, init_cache,
-                          init_encdec_params, init_params)
+from repro.models import (decode_step, encdec_forward, encdec_prefill,
+                          forward, init_cache, init_encdec_params,
+                          init_params)
+from repro.serving.engine import make_decode_step
 from repro.training.optimizer import adamw_init
 
 RNG = jax.random.PRNGKey(0)
@@ -89,6 +94,125 @@ def test_decode_matches_forward(arch):
     dec = jnp.stack(outs, axis=1).astype(jnp.float32)
     rel = float(jnp.abs(full - dec).max() / (jnp.abs(full).max() + 1e-9))
     assert rel < 0.05      # bf16 accumulation-order differences only
+
+
+# slot b takes PROMPT[b] tokens alone, then JOINT more beside the others;
+# the longest passes a 32-entry ring (window 16) and wraps
+PROMPT, JOINT, SLOT_MAX_LEN = (3, 14, 36), 6, 48
+
+
+def _slot_frames(cfg, slot):
+    """An encoder-decoder slot's source frames (None for a decoder)."""
+    if cfg.encoder is None:
+        return None
+    return jax.random.normal(jax.random.PRNGKey(slot), (1, 12, cfg.d_model),
+                             jnp.float32)
+
+
+def _slot_start(cfg, params, frames):
+    """An empty one-slot cache; an encoder-decoder's holds the cross K/V of
+    ``frames`` and has taken the start token 0."""
+    if frames is None:
+        return init_cache(cfg, 1, SLOT_MAX_LEN)
+    _, cache, _ = encdec_prefill(params, cfg, frames,
+                                 jnp.zeros((1, 1), jnp.int32), SLOT_MAX_LEN)
+    return cache
+
+
+def _slot_forward(cfg, params, frames, toks):
+    """The full-sequence logits of the tokens a slot took, one row per
+    token in ``toks`` (after the start token of an encoder-decoder)."""
+    if frames is None:
+        return forward(params, cfg, tokens=toks)[0]
+    dec = jnp.concatenate([jnp.zeros((1, 1), toks.dtype), toks], axis=1)
+    return encdec_forward(params, cfg, frames, dec)[0, 1:]
+
+
+def _slot_axis(path):
+    """The slot (batch) axis of a cache leaf: after the layer axis of the
+    scanned leaves, first in the prefix layers' leaves and ``len``."""
+    return 0 if path[0].key in ("len", "prefix") else 1
+
+
+def _assert_written_only(before, after):
+    """Between two caches a step may change only what it writes: each
+    slot's entry at its length (modulo a ring's size) in attention K/V
+    and MLA's latent and rope key, every SSM state; ``len`` advances by
+    one, cross-attention K/V stay as they were."""
+    lens = before["len"]
+
+    def check(path, old, new):
+        name = path[-1].key
+        if name in ("ssm", "conv_x", "conv_bc"):
+            return
+        if name == "len":
+            np.testing.assert_array_equal(new, old + 1)
+            return
+        old = old.copy()
+        if name in ("k", "v", "c_kv", "k_pe"):
+            seq = _slot_axis(path) + 1
+            for b, n in enumerate(lens):
+                at = (slice(None),) * (seq - 1) + (b, n % old.shape[seq])
+                old[at] = new[at]
+        assert old.tobytes() == new.tobytes(), jax.tree_util.keystr(path)
+
+    jax.tree_util.tree_map_with_path(check, before, after)
+
+
+@pytest.mark.parametrize("arch", ["internlm2_1_8b", "gemma3_12b",
+                                  "mixtral_8x7b", "deepseek_v2_lite_16b",
+                                  "mamba2_2_7b", "zamba2_7b",
+                                  "qwen1_5_32b", "seamless_m4t_large_v2"])
+def test_donated_step_serves_slots_of_unequal_lengths(arch):
+    """The engine's step (jitted, cache donated) over 3 slots that hold
+    3, 14 and 36 tokens: each slot's logits are those of the same tokens
+    decoded alone, and those a full-sequence forward pass gives within any
+    window (float32, to rounding); every step consumes the cache it is given, and writes
+    nothing but each slot's new entry or state."""
+    cfg = dataclasses.replace(C.get_reduced(arch), dtype="float32")
+    params = (init_params(RNG, cfg) if cfg.encoder is None
+              else init_encdec_params(RNG, cfg))
+    step = make_decode_step(cfg)
+    toks = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(7), (len(PROMPT), max(PROMPT) + JOINT), 0,
+        cfg.vocab_size), np.int32)
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))
+    # a ring keeps more than its window (models/transformer.ring_size), so
+    # the full sequence agrees with it only within the window
+    horizon = min([s.window for s in cfg.block_pattern if s.window],
+                  default=SLOT_MAX_LEN)
+    starts, alone = [], []
+    for b, n in enumerate(PROMPT):
+        frames = _slot_frames(cfg, b)
+        cache = _slot_start(cfg, params, frames)
+        full = f32(_slot_forward(cfg, params, frames,      # causal
+                                 jnp.asarray(toks[b:b + 1])))
+        for t in range(n + JOINT):
+            if t == n:
+                starts.append(jax.tree.map(np.array, cache))
+            _, logits, cache = step(params, jnp.asarray(toks[b:b + 1, t:t + 1]),
+                                    cache)
+            row = f32(logits[0])
+            if t < horizon:
+                np.testing.assert_allclose(row, full[t], rtol=0,
+                                           atol=1e-4 * np.abs(full[t]).max())
+            if t >= n:
+                alone.append((b, t, row))
+    cache = jax.tree_util.tree_map_with_path(
+        lambda path, *xs: jnp.asarray(np.concatenate(xs, _slot_axis(path))),
+        *starts)
+    fed = np.asarray(PROMPT)
+    for j in range(JOINT):
+        before = jax.tree.map(np.array, cache)
+        tok = toks[np.arange(len(PROMPT)), fed + j][:, None]
+        _, logits, new = step(params, jnp.asarray(tok), cache)
+        assert all(x.is_deleted() for x in jax.tree.leaves(cache))
+        _assert_written_only(before, jax.tree.map(np.array, new))
+        cache = new
+        for b, t, row in alone:
+            if t == fed[b] + j:     # batched in another order
+                np.testing.assert_allclose(f32(logits[b]), row, rtol=0,
+                                           atol=1e-5 * np.abs(row).max())
 
 
 def test_param_counts_match_ir():

@@ -97,6 +97,43 @@ def test_step_reads_each_slots_own_length(small, budget):
     assert (budget is None) == (rep.preemptions == 0)
 
 
+def test_step_consumes_the_cache_through_restarts_and_preemption(small):
+    """The engine donates its cache to every step: the cache passed to
+    ``_decode`` is deleted by the call (the CPU honours donation), so
+    nothing may read it after.  A restart mid-run (``snapshot`` then
+    ``restore`` between iterations) and preemption under a KV budget still
+    serve every request, with the tokens of an undisturbed run."""
+    cfg, params = small
+    reqs = _reqs(cfg, 5, gen=8, ctx=16)
+    plain = ServingEngine(cfg, params, max_batch=3, max_len=64,
+                          kv_token_budget=40).run(reqs, time_scale=0.0)
+    eng = ServingEngine(cfg, params, max_batch=3, max_len=64,
+                        kv_token_budget=40)
+    step, admit, given, restarts = eng._decode, eng._admit, [], []
+
+    def decode(p, toks, cache):
+        out = step(p, toks, cache)
+        given.append(jax.tree.leaves(cache["blocks"]))
+        return out
+
+    def restart_then_admit(now):
+        if not restarts and len(given) >= 24 and \
+                any(s.active for s in eng.slots):
+            restarts.append(eng.snapshot())
+            eng.restore(restarts[-1])
+            assert not any(s.active for s in eng.slots)
+        return admit(now)
+
+    eng._decode, eng._admit = decode, restart_then_admit
+    rep = eng.run(reqs, time_scale=0.0)
+    assert restarts and restarts[0]["inflight"]
+    assert all(x.is_deleted() for leaves in given for x in leaves)
+    assert rep.preemptions > 0
+    assert {r.rid: r.tokens for r in rep.results} == \
+        {r.rid: r.tokens for r in plain.results}
+    assert len(rep.results) == len(reqs)
+
+
 def test_router_spreads_load(small):
     cfg, params = small
     engines = [ServingEngine(cfg, params, max_batch=2, max_len=64)

@@ -5,8 +5,10 @@ described rather than present.  That refuses what interpret mode accepts:
 block shapes off the (8, 128) tiling, rank-1 SMEM/VMEM blocks, programs
 that do not fit the chip's 16 GB.  Here every Pallas kernel compiles with
 ``interpret=False`` at a real width (internlm2-1.8b's attention and norm,
-mamba2-2.7b's scan), and internlm2-1.8b's FULL ``decode_step`` compiles at
-batch 8, max_len 2048 within the chip's memory.
+mamba2-2.7b's scan), and the serving engine's step (``make_decode_step``)
+for internlm2-1.8b FULL compiles at batch 8, max_len 2048 within the
+chip's memory, and at batch 16 updates its donated cache in place: the
+output aliases the whole cache, and no layer of it is copied out.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -16,6 +18,8 @@ the chip fails the tests.
 The persistent compilation cache is off around these compiles (a program
 compiled for a described chip cannot be read back without one).
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +35,7 @@ from repro.kernels.flash_attention.flash_attention import \
 from repro.kernels.rmsnorm.rmsnorm import rms_norm_pallas
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan_pallas
 from repro.models import transformer as T
+from repro.serving.engine import make_decode_step
 
 LM = C.get_config("internlm2_1_8b")
 SSM = C.get_config("mamba2_2_7b")
@@ -96,17 +101,63 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_internlm2_full_decode_step_fits_one_v5e(one_chip):
+def _engine_step(sharding, batch):
+    """The engine's donated step for internlm2-1.8b FULL at ``batch`` slots
+    of ``MAX_LEN``, compiled for the described chip; and its cache."""
     params = jax.eval_shape(
         lambda: T.init_params(jax.random.PRNGKey(0), LM))
-    cache = jax.eval_shape(lambda: T.init_cache(LM, BATCH, MAX_LEN))
+    cache = jax.eval_shape(lambda: T.init_cache(LM, batch, MAX_LEN))
     place = lambda tree: jax.tree.map(
-        lambda x: _spec(one_chip, x.shape, x.dtype), tree)
-    compiled = jax.jit(lambda p, t, c: T.decode_step(p, LM, t, c)).lower(
-        place(params), _spec(one_chip, (BATCH, 1), jnp.int32),
+        lambda x: _spec(sharding, x.shape, x.dtype), tree)
+    compiled = make_decode_step(LM).lower(
+        place(params), _spec(sharding, (batch, 1), jnp.int32),
         place(cache)).compile()
+    return compiled, cache
+
+
+def test_internlm2_full_decode_step_fits_one_v5e(one_chip):
+    compiled, _ = _engine_step(one_chip, BATCH)
     mem = compiled.memory_analysis()
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 3.5e9      # bf16 weights + cache
     assert held < TPU_V5E.hbm_bytes, held
+
+
+def _top_level_outputs(hlo: str):
+    """(computation, output type) of every instruction outside a fusion's
+    body: the values the compiled program holds in memory."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", hlo))
+    comp, out = None, []
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        inst = re.match(r"\s+(?:ROOT )?%\S+ = (\w+\[[\d,]*\])", line)
+        if inst and comp not in fused:
+            out.append((comp, inst.group(1)))
+    return out
+
+
+def test_engine_step_updates_the_donated_cache_in_place(one_chip):
+    """At the benchmark's batch (16 slots of 2048): the step's output
+    aliases the whole cache, its temporaries stay under one layer's K leaf,
+    and no instruction outside a fusion yields the squeezed (16, 2048, 8,
+    128) layer that a scan over the cache as input slices out and then
+    restacks.  (The attention's read of a layer may still be staged whole
+    into the chip's fast memory, as a (1, 16, 2048, 8, 128) slice.)"""
+    batch = 16
+    compiled, cache = _engine_step(one_chip, batch)
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    k = cache["blocks"]["l0"]["k"]
+    layer_bytes = k.size // k.shape[0] * k.dtype.itemsize
+    assert cache_bytes > 3.2e9 and layer_bytes == 64 * 2 ** 20
+    assert mem.alias_size_in_bytes >= cache_bytes, mem
+    assert mem.temp_size_in_bytes < layer_bytes, mem
+    shape = lambda dims: "bf16[%s]" % ",".join(map(str, dims))
+    outputs = _top_level_outputs(compiled.as_text())
+    assert shape(k.shape) in {t for _, t in outputs}   # the parse sees it
+    assert not [o for o in outputs if o[1] == shape(k.shape[1:])]
