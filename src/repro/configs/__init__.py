@@ -7,7 +7,7 @@ Input-shape cells (applied per arch; see launch/shapes.py):
     prefill_32k  seq 32768 x global_batch 32    (prefill)
     decode_32k   seq 32768 x global_batch 128   (serve_step, 1 new token)
     long_500k    seq 524288 x global_batch 1    (serve_step, sub-quadratic
-                                                 archs only — see DESIGN.md)
+                                                 archs only)
 """
 
 from __future__ import annotations
@@ -59,7 +59,7 @@ def get_reduced(name: str) -> ModelConfig:
 
 
 def shape_skips(name: str) -> Dict[str, str]:
-    """shape id -> reason, for cells this arch skips (DESIGN.md rules)."""
+    """shape id -> reason, for cells this arch skips."""
     return getattr(_module(name), "SKIP_SHAPES", {})
 
 

@@ -67,5 +67,4 @@ REDUCED = ModelConfig(
 )
 
 SKIP_SHAPES = {"long_500k":
-               "MLA latent cache is compressed but attention is still full "
-               "(DESIGN.md rule)"}
+               "MLA latent cache is compressed but attention is still full"}

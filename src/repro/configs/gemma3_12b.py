@@ -6,7 +6,7 @@ Block = 5 local (sliding-window 1024) + 1 global layer, repeated 8x (the
 smallest non-repetitive cell chain — the Transformer-IR block).  long_500k
 RUNS for this arch: 5/6 of layers have window-bounded KV (ring caches), so
 decode memory is sub-quadratic-dominated; the global layers' KV is
-mesh-sharded (see DESIGN.md §long_500k).
+mesh-sharded.
 """
 
 from repro.models.config import LayerSpec, ModelConfig

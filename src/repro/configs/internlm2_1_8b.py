@@ -1,8 +1,7 @@
 """internlm2-1.8b [dense] — 24L d_model=2048 16H (GQA kv=8) d_ff=8192
 vocab=92544.  [arXiv:2403.17297; hf]
 
-Pure full attention -> long_500k is SKIPPED (quadratic-regime artifact;
-see DESIGN.md §long_500k).
+Pure full attention -> long_500k is SKIPPED (quadratic-regime artifact).
 """
 
 from repro.models.config import LayerSpec, ModelConfig
@@ -31,4 +30,4 @@ REDUCED = ModelConfig(
     d_ff=192,
 )
 
-SKIP_SHAPES = {"long_500k": "pure full-attention arch (DESIGN.md rule)"}
+SKIP_SHAPES = {"long_500k": "pure full-attention arch"}

@@ -32,4 +32,4 @@ REDUCED = ModelConfig(
     d_ff=256,
 )
 
-SKIP_SHAPES = {"long_500k": "pure full-attention arch (DESIGN.md rule)"}
+SKIP_SHAPES = {"long_500k": "pure full-attention arch"}

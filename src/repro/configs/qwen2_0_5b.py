@@ -3,8 +3,7 @@ vocab=151936, QKV bias, tied embeddings.  [arXiv:2407.10671; hf]
 
 Pure full attention -> long_500k SKIPPED.  Note the awkward head count
 (14 heads, kv=2): TP degrees are restricted to divisors of 14 for the
-attention cell — the planner handles this via cell-level DP (DESIGN.md
-§Arch-applicability).
+attention cell — the planner handles this via cell-level DP.
 """
 
 from repro.models.config import LayerSpec, ModelConfig
@@ -37,4 +36,4 @@ REDUCED = ModelConfig(
     tie_embeddings=True,
 )
 
-SKIP_SHAPES = {"long_500k": "pure full-attention arch (DESIGN.md rule)"}
+SKIP_SHAPES = {"long_500k": "pure full-attention arch"}

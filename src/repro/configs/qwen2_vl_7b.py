@@ -38,4 +38,4 @@ REDUCED = ModelConfig(
     embeds_input=True,
 )
 
-SKIP_SHAPES = {"long_500k": "pure full-attention arch (DESIGN.md rule)"}
+SKIP_SHAPES = {"long_500k": "pure full-attention arch"}

@@ -4,7 +4,7 @@ d_model=1024 16H (kv=16) d_ff=8192 vocab=256206.  [arXiv:2308.11596; hf]
 Transformer BACKBONE only: the speech frontend is a stub — input_specs()
 provides precomputed frame embeddings for the encoder.  Decoder layers are
 self-attn + cross-attn + FFN (plain, non-gated).  Full attention + enc-dec
-audio operating regime -> long_500k SKIPPED (DESIGN.md).
+audio operating regime -> long_500k SKIPPED.
 """
 
 from repro.models.config import EncoderConfig, LayerSpec, ModelConfig
@@ -44,5 +44,5 @@ REDUCED = ModelConfig(
 
 SKIP_SHAPES = {
     "long_500k": "enc-dec audio model, full attention; 500k-token target "
-                 "decode is outside its operating regime (DESIGN.md rule)",
+                 "decode is outside its operating regime",
 }

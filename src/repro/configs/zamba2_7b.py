@@ -5,8 +5,8 @@ repeat.  [arXiv:2411.15242; unverified tier]
 We model the 81 layers as 6 Mamba2 layers x 13 repeats (78) + 13
 applications of ONE shared attention+MLP block (weights tied across
 repeats — the Zamba2 signature).  Cell-level DP is disabled for the shared
-block: replicating it would break the weight tying (DESIGN.md
-§Arch-applicability).  Hybrid -> long_500k RUNS.
+block: replicating it would break the weight tying.  Hybrid -> long_500k
+RUNS.
 """
 
 from repro.models.config import LayerSpec, ModelConfig

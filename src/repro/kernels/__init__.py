@@ -1,22 +1,18 @@
-"""Pallas TPU kernels for the serving hot spots.
+"""Pallas TPU kernel candidates for the serving hot spots.
 
-Each kernel package ships three files:
+No served path calls these kernels: the layers in ``repro.layers`` run
+plain XLA.  Each kernel is compiled for a described v5e by
+tests/test_tpu_compile.py, checked against its oracle in interpret mode by
+tests/test_kernels.py and on the chip by chip_smoke.py.  A kernel that
+wins a cell is called from its layer, chosen by what the code can observe
+(the platform); one that wins none is deleted.
+
+Each kernel package holds two files, imported directly:
   * ``<name>.py`` — the pl.pallas_call kernel with explicit BlockSpec VMEM
-    tiling (TPU is the TARGET; validated with interpret=True on CPU,
-    compiled for a described v5e by tests/test_tpu_compile.py and run
-    against ``ref.py`` on the chip by chip_smoke.py),
-  * ``ops.py``    — the jit'd public wrapper that dispatches kernel vs.
-    pure-jnp fallback,
+    tiling,
   * ``ref.py``    — the pure-jnp oracle the tests assert_allclose against.
 
 Kernels: flash_attention (prefill), decode_attention (one token vs KV
-cache, flash-decoding tiling), ssd_scan (Mamba2 chunked SSD), rmsnorm.
+cache, flash-decoding tiling; it transposes and pads the whole unstacked
+cache per call), ssd_scan (Mamba2 chunked SSD), rmsnorm.
 """
-
-from .flash_attention.ops import flash_attention
-from .decode_attention.ops import decode_attention
-from .ssd_scan.ops import ssd_scan
-from .rmsnorm.ops import fused_rms_norm
-
-__all__ = ["decode_attention", "flash_attention", "fused_rms_norm",
-           "ssd_scan"]

@@ -5,13 +5,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # device count at first init).  Everything below is ordinary code.
 
 import argparse          # noqa: E402
-import functools         # noqa: E402
 import json              # noqa: E402
 import time              # noqa: E402
 import traceback         # noqa: E402
 
 import jax               # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro import configs as C                          # noqa: E402
@@ -22,8 +20,7 @@ from repro.launch import hlo_utils                      # noqa: E402
 from repro.models import transformer as T               # noqa: E402
 from repro.models import encdec as ED                   # noqa: E402
 from repro.models.config import ModelConfig             # noqa: E402
-from repro.parallel.sharding import (batch_pspec, cache_pspecs,  # noqa: E402
-                                     param_pspecs)
+from repro.parallel.sharding import cache_pspecs, param_pspecs  # noqa: E402
 from repro.training.optimizer import adamw_init         # noqa: E402
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
@@ -31,20 +28,13 @@ mesh) cell and record memory/cost/collective analysis.
 
 This proves the distribution config is coherent without hardware: a
 sharding mismatch, compile-time OOM, or unsupported collective fails the
-cell.  Results feed EXPERIMENTS.md §Dry-run and the §Roofline analysis.
+cell.  ``--out`` writes the records that benchmarks/roofline.py reads.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun                # all cells
     PYTHONPATH=src python -m repro.launch.dryrun --arch gemma3-12b \
         --shape train_4k --multi-pod both --out results/dryrun.json
 """
-
-
-# fp8 KV-cache overrides: cells whose bf16 KV cache cannot fit the pod
-# (see EXPERIMENTS.md §Dry-run notes).
-CACHE_DTYPE_OVERRIDES = {
-    ("qwen1_5_32b", "decode_32k"): jnp.float8_e4m3fn,
-}
 
 
 def _struct_params(cfg: ModelConfig):
@@ -140,10 +130,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
     norm = C.ALIASES.get(arch, arch).replace("-", "_").replace(".", "_")
     if norm in _MB1_ARCHS and cfg_override is None:
         microbatches = 1
-    cache_dtype = CACHE_DTYPE_OVERRIDES.get(
-        (C.ALIASES.get(arch, arch).replace("-", "_").replace(".", "_"),
-         shape_name))
-    specs = input_specs(cfg, cell, cache_dtype=cache_dtype)
+    specs = input_specs(cfg, cell)
     params = _struct_params(cfg)
     daxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     dax = daxes if len(daxes) > 1 else daxes[0]
@@ -226,9 +213,8 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
                - mem.get("alias_size_in_bytes", 0))
     # Model-based per-device estimate: XLA's argument sizes (exact, sharded)
     # + an analytic workspace.  The raw CPU-backend temp is inflated by
-    # float-normalization (bf16->f32 weight copies, fp8->f16 cache upcasts)
-    # hoisted out of the layer loop — buffers a real TPU (native bf16/fp8)
-    # never materializes; see EXPERIMENTS.md §Dry-run notes.
+    # float-normalization (bf16->f32 weight copies) hoisted out of the
+    # layer loop — buffers a real TPU (native bf16) never materializes.
     ws = _analytic_workspace(cfg, cell, mesh, microbatches)
     per_dev_model = mem.get("argument_size_in_bytes", 0) + ws
     return {

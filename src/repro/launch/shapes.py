@@ -11,7 +11,6 @@ Cells (applied per arch; skips per configs/<arch>.SKIP_SHAPES):
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,8 +41,7 @@ def _struct_like(tree):
     return jax.tree.map(lambda x: SDS(x.shape, x.dtype), tree)
 
 
-def input_specs(cfg: ModelConfig, shape: ShapeCell,
-                cache_dtype=None) -> dict:
+def input_specs(cfg: ModelConfig, shape: ShapeCell) -> dict:
     """ShapeDtypeStruct stand-ins for one (arch x shape) cell.
 
     train  -> {"tokens","labels"} (+ "frames"/"embeds" for stub frontends)
@@ -79,6 +77,5 @@ def input_specs(cfg: ModelConfig, shape: ShapeCell,
     cache = jax.eval_shape(
         lambda: T.init_cache(cfg, B, S,
                              source_len=cfg.cross_source_len
-                             if cfg.cross_attn else 0,
-                             cache_dtype=cache_dtype))
+                             if cfg.cross_attn else 0))
     return {"tokens": SDS((B, 1), i32), "cache": _struct_like(cache)}

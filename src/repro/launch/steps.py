@@ -12,9 +12,6 @@ cache (the iteration-level batching engine calls this once per iteration).
 
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
@@ -33,7 +30,7 @@ def chunked_ce_loss(hidden: jnp.ndarray, head: jnp.ndarray,
     """Cross-entropy over sequence chunks.  hidden: (B, S, d) post-norm;
     head: (d, V); labels: (B, S).  fp32 log-softmax.
 
-    Memory discipline (measured on the 16x16 dry-run, see §Perf log):
+    Memory discipline:
       * the gold logit is h . head[:, label] computed via ONE gather of the
         label rows (same pattern as the forward embedding lookup) + a dot —
         never a (B, c, V) one-hot or take_along_axis over the vocab-sharded

@@ -2,9 +2,8 @@
 
 All layers are pure functions over parameter pytrees (dicts of jnp arrays);
 no framework (flax/haiku) dependency.  Shapes follow (batch, seq, dim)
-unless stated.  Perf-critical inner loops (attention, SSD scan) have Pallas
-TPU kernels in repro.kernels; these layers call the ops.py dispatchers,
-which fall back to the pure-jnp reference on CPU.
+unless stated.  These layers run plain XLA on every backend; the Pallas
+candidates in repro.kernels are called by no layer.
 """
 
 from .norms import layer_norm, rms_norm

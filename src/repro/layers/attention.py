@@ -5,8 +5,8 @@ Two execution paths:
   * ``*_attention``   — full-sequence (training / prefill).  Uses a
     blockwise online-softmax implementation (`blockwise_attention`) so the
     S x S score matrix is never materialized — mandatory for the 32k-prefill
-    dry-run shapes, and the same tiling the Pallas kernel
-    (repro/kernels/flash_attention) implements in VMEM.
+    dry-run shapes.  The Pallas candidate repro/kernels/flash_attention
+    tiles the same way in VMEM; no layer calls it.
   * ``*_decode_step`` — one new token against a KV cache (serving).  A
     step takes one layer's cache, or the stacked caches of the decoder's
     layer scan and the layer to use: it writes one entry per slot into the
@@ -18,7 +18,6 @@ Parameters are plain dicts of jnp arrays; init fns take explicit dims.
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Optional, Tuple
 
@@ -295,15 +294,14 @@ def _project_qkv(params: dict, x: jnp.ndarray, n_heads: int,
 def gqa_attention(params: dict, x: jnp.ndarray, positions: jnp.ndarray,
                   *, n_heads: int, n_kv_heads: int, head_dim: int,
                   window: Optional[int] = None, rope: str = "rope",
-                  rope_theta: float = 10000.0,
-                  attn_impl=blockwise_attention) -> jnp.ndarray:
+                  rope_theta: float = 10000.0) -> jnp.ndarray:
     """Full-sequence GQA (training / prefill).  x: (B, S, d_model)."""
     q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
     if rope == "rope":
         q, k = apply_rope(q, k, positions, rope_theta)
     elif rope == "mrope":
         q, k = apply_mrope(q, k, positions, theta=rope_theta)
-    out = attn_impl(q, k, v, causal=True, window=window)
+    out = blockwise_attention(q, k, v, causal=True, window=window)
     B, S = x.shape[:2]
     return out.reshape(B, S, n_heads * head_dim) @ params["wo"]
 
@@ -364,12 +362,13 @@ def gqa_decode_step(params: dict, x: jnp.ndarray, cache_k: jnp.ndarray,
 
     # Grouped by KV head: query head h attends with KV head h // g, so q is
     # viewed as (B, 1, Hkv, g, D) and each cached K/V entry is read once —
-    # no (B, Smax, Hq, D) repeat is built.  fp8 caches are upcast to the
-    # compute dtype on read (a no-op for a cache already in it).  The shard
-    # hints pin the cache and the scores to the cache's SEQUENCE sharding,
-    # making the softmax+readout a flash-decoding combine (psum of small
-    # (B,H) stats + (B,H,D) partials) instead of a per-layer KV all-gather
-    # — §Perf iteration 3 (no-ops off-mesh).
+    # no (B, Smax, Hq, D) repeat is built.  The cache is read in the
+    # compute dtype: a no-op for init_cache's cache, kept in the model's
+    # dtype; a cache of another dtype is upcast on read.  The shard hints
+    # pin the cache and the scores to the cache's SEQUENCE sharding, making
+    # the softmax+readout a flash-decoding combine (psum of small (B,H)
+    # stats + (B,H,D) partials) instead of a per-layer KV all-gather
+    # (no-ops off-mesh).
     from .hints import data_axis_names, shard_hint
     daxes = data_axis_names() or None
     g = n_heads // n_kv_heads
@@ -427,8 +426,7 @@ def mla_attention(params: dict, x: jnp.ndarray, positions: jnp.ndarray,
                   *, n_heads: int, kv_lora_rank: int,
                   qk_nope_head_dim: int = 128, qk_rope_head_dim: int = 64,
                   v_head_dim: int = 128, rope_theta: float = 10000.0,
-                  rope_scaling=None,
-                  attn_impl=blockwise_attention) -> jnp.ndarray:
+                  rope_scaling=None) -> jnp.ndarray:
     """Full-sequence MLA.  The latent c_kv is shared across heads; the RoPE
     key part k_pe is computed once and broadcast (DeepSeek-V2 §2.1)."""
     B, S, _ = x.shape
@@ -444,11 +442,11 @@ def mla_attention(params: dict, x: jnp.ndarray, positions: jnp.ndarray,
         [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:3]
                                   + (qk_rope_head_dim,))], axis=-1)
     q_full = jnp.concatenate([q_nope, q_pe], axis=-1)
-    # attn_impl scales by qk_head^-0.5; q carries YaRN's factor
+    # blockwise_attention scales by qk_head^-0.5; q carries YaRN's factor
     factor = _yarn_softmax_factor(rope_scaling)
     if factor != 1.0:
         q_full = (q_full.astype(jnp.float32) * factor).astype(q_full.dtype)
-    out = attn_impl(q_full, k_full, v, causal=True, window=None)
+    out = blockwise_attention(q_full, k_full, v, causal=True, window=None)
     return out.reshape(B, S, n_heads * v_head_dim) @ params["wo"]
 
 
@@ -478,8 +476,8 @@ def mla_decode_step(params: dict, x: jnp.ndarray, cache_c: jnp.ndarray,
                               layer)
 
     # expand every cached latent to per-head K_nope and V (the simple form;
-    # the absorbed form scores q against the latent directly); fp8 caches
-    # are upcast to the compute dtype on read
+    # the absorbed form scores q against the latent directly), read in the
+    # compute dtype as in gqa_decode_step
     k_nope, v = _mla_expand(params,
                             cache_layer(cache_c, layer).astype(x.dtype),
                             n_heads, qk_nope_head_dim,

@@ -7,8 +7,9 @@ axis = expert parallelism under pjit) and exactly matches the gathered
 result — the right trade for smoke tests, training at modest expert counts,
 and the dry-run (where only the sharded HLO matters; XLA's SPMD partitioner
 turns the expert einsum + masked routing into the standard EP all-to-all
-pattern).  A token-dropping capacity-based gathered path is in
-repro/parallel/ep.py for the serving engine.
+pattern).  This is the layer the serving engine runs.  A token-dropping
+capacity-based gathered dispatch is in repro/parallel/ep.py; only the
+multi-device tests run it.
 """
 
 from __future__ import annotations
@@ -18,11 +19,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-# MoE dense-dispatch layout for non-EP-shardable expert counts; see the
-# measured trade-off in moe_forward (scan-over-experts wins forward-only
-# serving, chunk-major wins training backward traffic).
-CHUNK_MAJOR = False
 
 
 def init_moe(rng, d_model: int, d_ff_expert: int, n_routed: int,
@@ -109,7 +105,6 @@ def _experts(params: dict, x: jnp.ndarray,
              combine: jnp.ndarray) -> jnp.ndarray:
     """Every routed expert on every token, weighted by ``combine``."""
     from .hints import mesh_axis_size
-    B, S, d = x.shape
     n_routed = params["router"].shape[1]
     m = mesh_axis_size("model")
     gated = "w_gate" in params
@@ -123,53 +118,13 @@ def _experts(params: dict, x: jnp.ndarray,
             h = jax.nn.gelu(up)
         y = jnp.einsum("ebsf,efd->ebsd", h, params["w_down"])
         out = jnp.einsum("ebsd,bse->bsd", y, combine.astype(y.dtype))
-    elif CHUNK_MAJOR:
-        # chunk-major dense dispatch: for each TOKEN chunk, run ALL experts
-        # in one stacked einsum and contract (expert, d_ff) in one step —
-        # one TP all-reduce per chunk.  Measured (§Perf iteration 2a/2e):
-        # 16% LESS all-reduce than scan-over-experts for TRAINING (the
-        # backward can't defer per-expert psums) but 3.65x MORE for
-        # forward-only prefill (XLA defers the scan layout's psums to one
-        # per layer).  Serving is this system's primary regime, so
-        # scan-over-experts is the default; flip CHUNK_MAJOR for
-        # training-heavy deployments.
-        comb_t = combine.transpose(2, 0, 1).astype(x.dtype)  # (E,B,S)
-        T_tok = B * S
-        ck = min(4096, T_tok)
-        T_pad = -(-T_tok // ck) * ck
-        xf = x.reshape(T_tok, d)
-        cf = comb_t.reshape(n_routed, T_tok)
-        if T_pad != T_tok:
-            xf = jnp.pad(xf, ((0, T_pad - T_tok), (0, 0)))
-            cf = jnp.pad(cf, ((0, 0), (0, T_pad - T_tok)))
-        xc = xf.reshape(T_pad // ck, ck, d)
-        cc = cf.reshape(n_routed, T_pad // ck, ck).transpose(1, 0, 2)
-
-        w_up, w_down = params["w_up"], params["w_down"]
-        w_gate = params.get("w_gate")
-
-        def chunk_step(carry, inp):
-            xk, ce = inp                        # (ck, d), (E, ck)
-            up = jnp.einsum("cd,edf->ecf", xk, w_up)
-            if gated:
-                gt = jnp.einsum("cd,edf->ecf", xk, w_gate)
-                h = jax.nn.silu(gt) * up
-            else:
-                h = jax.nn.gelu(up)
-            h = h * ce[:, :, None]              # fold combine weights
-            yk = jnp.einsum("ecf,efd->cd", h, w_down)  # ONE reduce
-            return carry, yk
-
-        _, ys = jax.lax.scan(chunk_step, 0.0, (xc, cc))
-        out = ys.reshape(T_pad, d)[:T_tok].reshape(B, S, d)
     else:
-        # scan-over-experts (default): one expert's WHOLE-TENSOR
-        # intermediates at a time (with the d_ff dim TP-sharded these are
-        # ~tokens x d_ff/16 — small), accumulated into a full-tensor carry.
-        # Keeping the expert body a straight-line matmul chain (no inner
-        # token-chunk loop!) lets XLA defer the per-expert partial
-        # reductions to ONE all-reduce per layer in forward-only programs —
-        # measured 16x less AR than a chunked body (§Perf iteration 2e).
+        # scan-over-experts: one expert's WHOLE-TENSOR intermediates at a
+        # time (with the d_ff dim TP-sharded these are ~tokens x d_ff/16 —
+        # small), accumulated into a full-tensor carry.  Keeping the expert
+        # body a straight-line matmul chain (no inner token-chunk loop)
+        # lets XLA defer the per-expert partial reductions to one
+        # all-reduce per layer in forward-only programs.
         comb_t = combine.transpose(2, 0, 1).astype(x.dtype)  # (E,B,S)
 
         def expert_step(y, inp):

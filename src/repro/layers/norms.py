@@ -1,8 +1,8 @@
 """Normalization layers (pure jnp).
 
-RMSNorm is the serving hot path's glue op; a fused Pallas kernel lives in
-repro/kernels/rmsnorm/ — this module is the canonical math used both as the
-model default and as the kernel oracle.
+RMSNorm is the serving hot path's glue op.  This module is the canonical
+math the models run, and the oracle of the Pallas candidate in
+repro/kernels/rmsnorm/, which no layer calls.
 """
 
 from __future__ import annotations

@@ -3,8 +3,9 @@
 Implements the chunked SSD algorithm (arXiv:2405.21060): within a chunk the
 computation is an attention-like matmul against a decay-masked score matrix
 (the "duality"); across chunks a small recurrent state (H, P, N) is carried
-by a scan.  This file is the pure-jnp reference; the Pallas TPU kernel in
-repro/kernels/ssd_scan tiles the same chunk structure into VMEM.
+by a scan.  This file is the pure-jnp math the models run; the Pallas
+candidate in repro/kernels/ssd_scan tiles the same chunk structure into
+VMEM, and no layer calls it.
 
 Parameter layout is TP-friendly: the x/z input projections and the x-conv
 are separate tensors column-shardable on d_inner (= SSD-head sharding, the

@@ -8,10 +8,11 @@ Design notes
   trillion-parameter model compile in the same time (the XLA-level mirror
   of APEX's Transformer-IR block extrapolation).
 * **Pure functions over dict pytrees** — no framework.  ``init_params``,
-  ``forward`` (training / prefill), ``prefill`` (forward + KV-cache
-  population) and ``decode_step`` (one token vs. cache) are the entire
-  public surface, shared by the trainer, the serving engine, and the
-  multi-pod dry-run.
+  ``forward`` (full sequence: training and the dry-run), ``init_cache``
+  and ``decode_step`` (one token vs. cache) are the entire public surface,
+  shared by the trainer, the serving engine, and the multi-pod dry-run.
+  The serving engine fills a slot's cache by replaying its prompt through
+  ``decode_step`` (``serving.engine.ServingEngine._prefill_slot``).
 * **The decode step carries the cache**: the layer scan iterates over the
   stacked parameters and the layer index, and carries the stacked cache;
   each layer writes its new entries (one per slot) or its new SSM state
@@ -26,7 +27,6 @@ Design notes
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import math
 from typing import Optional, Tuple
@@ -47,9 +47,10 @@ def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
 
 
-def ring_size(window: int, multiple: int = 16) -> int:
-    """Sliding-window ring-cache size: window+1 rounded up for sharding."""
-    return -(-(window + 1) // multiple) * multiple
+def ring_size(window: int) -> int:
+    """Sliding-window ring-cache size: window+1 rounded up to a multiple of
+    16 for sharding."""
+    return -(-(window + 1) // 16) * 16
 
 
 # ---------------------------------------------------------------------------
@@ -256,8 +257,7 @@ def forward(params: dict, cfg: ModelConfig,
 
     # nested per-layer checkpoints only pay off for multi-layer blocks
     # (gemma3's 6-deep pattern): with a single-layer block they re-remat
-    # the identical region, re-running every TP collective a third time
-    # (~+50% all-reduce traffic, measured on mixtral train_4k — §Perf).
+    # the identical region, re-running every TP collective a third time.
     nest_remat = remat and len(cfg.block_pattern) > 1
 
     def block_body(x, blk):
@@ -287,16 +287,12 @@ def forward(params: dict, cfg: ModelConfig,
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
-               source_len: int = 0, cache_dtype=None) -> dict:
-    """All-zero cache pytree.  Layout per scanned repeat (leading R axis):
-    attention -> k/v (R, B, Smax, Hkv, D); MLA -> latent + rope-key; SSM ->
-    fp32 state + conv window.  ``len``: (B,) valid lengths.
-
-    ``cache_dtype``: KV storage dtype — e.g. jnp.float8_e4m3fn for the
-    fp8-KV-cache serving mode (paper §2.5's KV quantization; required for
-    qwen1.5-32b decode_32k to fit a 256-chip v5e pod, see EXPERIMENTS.md).
-    """
-    dt = jnp.dtype(cache_dtype) if cache_dtype is not None else _dtype(cfg)
+               source_len: int = 0) -> dict:
+    """All-zero cache pytree in the model's dtype.  Layout per scanned
+    repeat (leading R axis): attention -> k/v (R, B, Smax, Hkv, D); MLA ->
+    latent + rope-key; SSM -> fp32 state + conv window.  ``len``: (B,)
+    valid lengths."""
+    dt = _dtype(cfg)
     R = cfg.block_repeat - cfg.first_k_dense
     hd = cfg.resolved_head_dim
 
@@ -322,10 +318,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         else:
             # ring caches are rounded up to a multiple of 16 so the
             # sequence dim shards cleanly over the model axis (a 4097-slot
-            # ring would replicate: measured as the dominant collective
-            # term of the mixtral decode cells). The ring then retains up
-            # to ring-1 >= window past tokens — a window enlarged by < 16
-            # tokens, documented in DESIGN.md.
+            # ring would replicate). The ring then retains up to
+            # ring-1 >= window past tokens — a window enlarged by < 16
+            # tokens.
             kv_len = max_len if spec.window is None \
                 else min(max_len, ring_size(spec.window))
             c = {
@@ -495,44 +490,3 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     x = rms_norm(x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
     return (x @ head)[:, 0, :], new_cache
-
-
-# ---------------------------------------------------------------------------
-# prefill: forward + cache population (serving engine)
-# ---------------------------------------------------------------------------
-
-def prefill(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
-            max_len: int, embeds: Optional[jnp.ndarray] = None,
-            lengths: Optional[jnp.ndarray] = None
-            ) -> Tuple[jnp.ndarray, dict]:
-    """Run the prompt through the model and build the cache by replaying
-    tokens through ``decode_step`` via scan (token-parallel prefill is an
-    optimization of the serving engine; correctness-first here, and the
-    per-token path reuses the exact decode math the engine serves with).
-
-    tokens: (B, S) right-padded; lengths: (B,) true lengths.
-    Returns (last-token logits (B, vocab), populated cache).
-    """
-    if cfg.cross_attn:
-        raise ValueError("encoder-decoder models prefill via "
-                         "repro.models.encdec.encdec_prefill")
-    B, S = tokens.shape[:2]
-    cache = init_cache(cfg, B, max_len)
-    if lengths is None:
-        lengths = jnp.full((B,), S, jnp.int32)
-
-    def step(cache, t):
-        tok = jax.lax.dynamic_slice_in_dim(tokens, t, 1, axis=1)
-        if embeds is not None:
-            emb = jax.lax.dynamic_slice_in_dim(embeds, t, 1, axis=1)
-            logits, cache = decode_step(params, cfg, tok, cache, embeds=emb)
-        else:
-            logits, cache = decode_step(params, cfg, tok, cache)
-        return cache, logits
-
-    cache, all_logits = jax.lax.scan(step, cache, jnp.arange(S))
-    # cache["len"] advanced S times; clamp to true lengths
-    cache["len"] = lengths
-    last = jnp.take_along_axis(
-        all_logits, (lengths - 1)[None, :, None], axis=0)[0]
-    return last, cache

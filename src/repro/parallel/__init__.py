@@ -1,6 +1,5 @@
 """Distribution layer: APEX plan -> JAX shardings, plus the explicitly
-scheduled parallel patterns (pipeline, expert-parallel dispatch,
-sequence-parallel flash-decoding)."""
+scheduled parallel patterns (pipeline, expert-parallel dispatch)."""
 
 from .sharding import batch_pspec, cache_pspecs, param_pspecs
 from .plan_sharding import plan_to_shardings

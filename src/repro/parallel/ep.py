@@ -16,15 +16,14 @@ optimized path the APEX planner's "ep" template maps to:
     rescale them.
 
 Exact top-k FLOPs (no dense-dispatch waste) and the paper's EP
-communication pattern (2 all-to-alls vs TP's all-reduce) — the §Perf
-hillclimb swaps this in for the MoE cells and measures the delta.
-Correctness is asserted against the dense oracle in tests/test_ep.py
-(with capacity high enough that nothing drops).
+communication pattern (2 all-to-alls vs TP's all-reduce).  No served path
+calls it yet.  Correctness is asserted against the dense oracle in
+tests/test_parallel.py (with capacity high enough that nothing drops).
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp

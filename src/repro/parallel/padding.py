@@ -15,8 +15,8 @@ no NaN).  The price is n_pad/n_heads extra attention FLOPs and KV bytes —
 20% for qwen1.5 versus 1500% redundant compute without it.  Same trick
 Megatron applies to vocab padding.
 
-Used by the §Perf hillclimb and available to the launchers via
-``pad_model_heads``.
+benchmarks/perf_iterations.py lowers a ``padded_config`` deployment;
+``pad_attention_heads`` pads a parameter tree to match it.
 """
 
 from __future__ import annotations

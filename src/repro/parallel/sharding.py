@@ -14,22 +14,20 @@ dry-run use):
                     assigned archs have fewer KV heads than the 16-wide
                     model axis (gemma3 kv=8, qwen2-vl kv=4, ...), and a
                     padded head-sharding wastes up to 4x cache memory.
-                    Under plain GSPMD this costs a per-layer KV all-gather
-                    at decode — the §Perf hillclimb replaces it with a
-                    shard_map flash-decoding combine (parallel/sp_decode).
+                    The decode step's shard hints keep attention on the
+                    sequence shards, so softmax and readout combine small
+                    partials instead of all-gathering each layer's cache
+                    (layers/attention.gqa_decode_step).
 
 Rules are path-based over the parameter pytree; anything unmatched is
 replicated.  Divisibility is checked and falls back to replication rather
 than failing — the dry-run prints fallbacks so silent inefficiency can't
-hide (DESIGN.md "no silent caps").
+hide.
 """
 
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.config import ModelConfig
@@ -138,8 +136,7 @@ def param_pspecs(params, cfg: ModelConfig, mesh: Mesh,
             spec[col + off] = "model"
         # The embedding table stays vocab-sharded ONLY: a 2D-sharded table
         # makes GSPMD replicate the gather/scatter-add (token lookup and its
-        # gradient), costing ~10 GB/device at 4k seq — measured, see
-        # EXPERIMENTS.md §Perf iteration log.
+        # gradient).
         if fsdp and d > 1 and leaf != "embed":
             # shard the largest still-unsharded effective dim over "data"
             best, best_size = None, 0

@@ -8,8 +8,8 @@ Implements iteration-level batching over a slot-based KV cache:
     the Batching Module's policy (core/batching.py), including preemption
     of the most-recently-admitted request when the token budget overflows;
   * each engine iteration runs ONE jitted decode step over all slots
-    (inactive slots are masked); prefill populates a request's slot via the
-    token-replay prefill;
+    (inactive slots are masked); ``_prefill_slot``, the one prefill,
+    populates a request's slot by replaying its prompt through that step;
   * the step is given the cache to consume (``make_decode_step`` donates
     it): it writes each slot's new entry in place and returns the one
     cache buffer, so the engine holds the cache once and drops its
@@ -222,8 +222,6 @@ class ServingEngine:
             (dict(r, arrival=r["arrival"] * time_scale) for r in requests),
             key=lambda r: r["arrival"])
         records: Dict[int, RequestResult] = {}
-        meta = {r["rid"]: dict(arrival=r["arrival"] * time_scale,
-                               first=None, start=None) for r in requests}
         now = 0.0
         iters = 0
         tr = self.trace

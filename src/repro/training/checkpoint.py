@@ -1,6 +1,6 @@
 """Fault-tolerant checkpointing: atomic, content-hashed, resumable.
 
-Design for 1000+ nodes (DESIGN.md §5):
+Design for 1000+ nodes:
   * every save writes to a temp directory then atomically renames — a
     crash mid-save leaves no partial checkpoint visible;
   * a MANIFEST (json) lists every array file with its sha256; restore

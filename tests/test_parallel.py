@@ -1,6 +1,6 @@
 """Multi-device parallel patterns (subprocess with 8 host devices):
-pipeline parallelism, EP dispatch, sequence-parallel decode, elastic
-resharding, plan->sharding translation."""
+pipeline parallelism, EP dispatch, the served decode step on a
+sequence-sharded cache, elastic resharding, plan->sharding translation."""
 
 import pytest
 
@@ -56,25 +56,50 @@ print("ep OK")
 """, devices=8)
 
 
-def test_sp_decode_matches_ref(subproc):
+def test_served_decode_step_sharded_matches_unsharded(subproc):
+    """The engine's decode step on a (data 2, model 4) mesh, parameters by
+    ``param_pspecs`` and the cache sequence-sharded by ``cache_pspecs``,
+    matches the same step on one device over steps at ragged lengths."""
     subproc("""
+import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from repro.parallel.sp_decode import sp_decode_attention
-from repro.kernels.decode_attention.ref import decode_attention_ref
+from repro import configs as C
 from repro.launch.mesh import make_mesh
+from repro.models import transformer as T
+from repro.parallel.sharding import cache_pspecs, param_pspecs, to_shardings
+from repro.serving.engine import make_decode_step
 
+cfg = dataclasses.replace(C.get_reduced("internlm2_1_8b"), dtype="float32")
+B, MAX_LEN, STEPS = 4, 32, 3
+params = T.init_params(jax.random.PRNGKey(0), cfg)
+cache = T.init_cache(cfg, B, MAX_LEN)
+ks = jax.random.split(jax.random.PRNGKey(1), 3)
+kv_shape = cache["blocks"]["l0"]["k"].shape
+cache["blocks"]["l0"] = {"k": jax.random.normal(ks[0], kv_shape),
+                         "v": jax.random.normal(ks[1], kv_shape)}
+cache["len"] = jnp.asarray([3, 10, 17, 29], jnp.int32)
+toks = jax.random.randint(ks[2], (STEPS, B, 1), 1, cfg.vocab_size)
+
+step = make_decode_step(cfg)
 mesh = make_mesh((2, 4), ("data", "model"))
-ks = jax.random.split(jax.random.PRNGKey(0), 3)
-B, Hq, Hkv, D, Smax = 4, 8, 2, 16, 64
-q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
-k = jax.random.normal(ks[1], (B, Smax, Hkv, D), jnp.float32)
-v = jax.random.normal(ks[2], (B, Smax, Hkv, D), jnp.float32)
-lens = jnp.asarray([5, 17, 40, 64])
-out = sp_decode_attention(q, k, v, lens, mesh)
-ref = decode_attention_ref(q, k, v, lens)
-np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4,
-                           atol=2e-4)
-print("sp_decode OK")
+ps = jax.device_put(params, to_shardings(param_pspecs(params, cfg, mesh),
+                                         mesh))
+cspecs = cache_pspecs(cache, cfg, mesh)
+assert cspecs["blocks"]["l0"]["k"][2] == "model", cspecs
+cs = jax.device_put(cache, to_shardings(cspecs, mesh))
+assert len(cs["blocks"]["l0"]["k"].sharding.device_set) == 8
+
+for t in range(STEPS):
+    _, want, cache = step(params, toks[t], cache)
+    with jax.set_mesh(mesh):
+        _, got, cs = step(ps, toks[t], cs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+for a, b in zip(jax.tree.leaves(cs), jax.tree.leaves(cache)):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                               atol=2e-5)
+np.testing.assert_array_equal(np.asarray(cs["len"]), [6, 13, 20, 32])
+print("sharded decode OK")
 """, devices=8)
 
 
