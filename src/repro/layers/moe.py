@@ -10,10 +10,16 @@ turns the expert einsum + masked routing into the standard EP all-to-all
 pattern).  This is the layer the serving engine runs.  A token-dropping
 capacity-based gathered dispatch is in repro/parallel/ep.py; only the
 multi-device tests run it.
+
+The routed-expert leaves (``w_gate``, ``w_up``, ``w_down``) are one
+layer's (E, ...) stacks, or, with a ``layer`` index, the decode step's
+layer-stacked (R, E, ...) leaves, read where they lie: one expert's block
+at a time, never a layer's stack copied out first.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -72,7 +78,7 @@ def _route(params: dict, x: jnp.ndarray, top_k: int,
 
 def moe_forward(params: dict, x: jnp.ndarray, top_k: int,
                 router_noise: Optional[jnp.ndarray] = None, *,
-                norm_topk_prob: bool = True) -> jnp.ndarray:
+                norm_topk_prob: bool = True, layer=None) -> jnp.ndarray:
     """x: (B, S, d_model) -> (B, S, d_model).
 
     Gates: with ``norm_topk_prob`` a softmax over the top-k logits
@@ -81,6 +87,11 @@ def moe_forward(params: dict, x: jnp.ndarray, top_k: int,
     false``, ``routed_scaling_factor`` 1).  Device ops carry the scopes
     ``moe.route`` (router and gates), ``moe.experts`` (routed experts) and
     ``moe.shared`` (shared experts).
+
+    ``params`` holds one layer's router and shared experts.  Its routed
+    expert leaves are that layer's (E, ...) stacks, or with ``layer`` the
+    layer-stacked (R, E, ...) leaves, of which layer ``layer`` is read
+    where it lies (``transformer.decode_step``).
 
     Two dense-dispatch layouts (both exact; gathered EP dispatch lives in
     parallel/ep.py):
@@ -93,7 +104,7 @@ def moe_forward(params: dict, x: jnp.ndarray, top_k: int,
     with jax.named_scope("moe.route"):
         combine = _route(params, x, top_k, router_noise, norm_topk_prob)
     with jax.named_scope("moe.experts"):
-        out = _experts(params, x, combine)
+        out = _experts(params, x, combine, layer)
     if "shared" in params:
         from .mlp import mlp_forward
         with jax.named_scope("moe.shared"):
@@ -101,22 +112,43 @@ def moe_forward(params: dict, x: jnp.ndarray, top_k: int,
     return out
 
 
-def _experts(params: dict, x: jnp.ndarray,
-             combine: jnp.ndarray) -> jnp.ndarray:
-    """Every routed expert on every token, weighted by ``combine``."""
+def _expert_weights(w: jnp.ndarray, layer, e=None) -> jnp.ndarray:
+    """Expert ``e``'s block of a routed-expert leaf ``w``, or with ``e``
+    None every expert's: from the layer's (E, ...) stack, or with ``layer``
+    from layer ``layer`` of the (R, E, ...) stack, indexed where it lies.
+    The one slice reads the block straight from the stacked leaf, so it
+    fuses into the matmul that consumes it (slicing the layer first, then
+    the expert, would copy the layer's stack out)."""
+    if e is None:
+        return w if layer is None else jax.lax.dynamic_index_in_dim(
+            w, layer, keepdims=False)
+    lead = () if layer is None else (layer,)
+    d, f = w.shape[-2:]
+    block = jax.lax.dynamic_slice(w, lead + (e, 0, 0),
+                                  (1,) * (len(lead) + 1) + (d, f))
+    return block.reshape(d, f)
+
+
+def _experts(params: dict, x: jnp.ndarray, combine: jnp.ndarray,
+             layer=None) -> jnp.ndarray:
+    """Every routed expert on every token, weighted by ``combine``.  The
+    expert leaves are one layer's stacks, or with ``layer`` the
+    layer-stacked leaves; each expert's blocks are read where they lie
+    (``_expert_weights``)."""
     from .hints import mesh_axis_size
     n_routed = params["router"].shape[1]
     m = mesh_axis_size("model")
     gated = "w_gate" in params
+    w = functools.partial(_expert_weights, layer=layer)
     if m > 1 and n_routed % m == 0:
         # expert-sharded einsum: (E,B,S,f) with E over "model"
-        up = jnp.einsum("bsd,edf->ebsf", x, params["w_up"])
+        up = jnp.einsum("bsd,edf->ebsf", x, w(params["w_up"]))
         if gated:
-            gate = jnp.einsum("bsd,edf->ebsf", x, params["w_gate"])
+            gate = jnp.einsum("bsd,edf->ebsf", x, w(params["w_gate"]))
             h = jax.nn.silu(gate) * up
         else:
             h = jax.nn.gelu(up)
-        y = jnp.einsum("ebsf,efd->ebsd", h, params["w_down"])
+        y = jnp.einsum("ebsf,efd->ebsd", h, w(params["w_down"]))
         out = jnp.einsum("ebsd,bse->bsd", y, combine.astype(y.dtype))
     else:
         # scan-over-experts: one expert's WHOLE-TENSOR intermediates at a
@@ -124,19 +156,18 @@ def _experts(params: dict, x: jnp.ndarray,
         # small), accumulated into a full-tensor carry.  Keeping the expert
         # body a straight-line matmul chain (no inner token-chunk loop)
         # lets XLA defer the per-expert partial reductions to one
-        # all-reduce per layer in forward-only programs.
+        # all-reduce per layer in forward-only programs.  The scan runs
+        # over expert ids, not over the weights: a weight slice that is a
+        # loop's operand would be copied out whole before the loop.
         comb_t = combine.transpose(2, 0, 1).astype(x.dtype)  # (E,B,S)
 
         def expert_step(y, inp):
-            if gated:
-                wu, wg, wd, ce = inp
-            else:
-                wu, wd, ce = inp
-            up = x @ wu
-            h = jax.nn.silu(x @ wg) * up if gated else jax.nn.gelu(up)
-            return y + (h @ wd) * ce[..., None], None
+            e, ce = inp
+            up = x @ w(params["w_up"], e=e)
+            h = (jax.nn.silu(x @ w(params["w_gate"], e=e)) * up if gated
+                 else jax.nn.gelu(up))
+            return y + (h @ w(params["w_down"], e=e)) * ce[..., None], None
 
-        xs = ((params["w_up"], params["w_gate"], params["w_down"], comb_t)
-              if gated else (params["w_up"], params["w_down"], comb_t))
-        out, _ = jax.lax.scan(expert_step, jnp.zeros_like(x), xs)
+        out, _ = jax.lax.scan(expert_step, jnp.zeros_like(x),
+                              (jnp.arange(n_routed), comb_t))
     return out

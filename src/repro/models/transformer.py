@@ -18,7 +18,9 @@ Design notes
   each layer writes its new entries (one per slot) or its new SSM state
   into the stacked leaves in place and reads its layer where it lies.  No
   layer's cache is sliced out or restacked, so a donated cache is updated
-  in place.
+  in place.  The MoE layers' routed-expert stacks are not among the
+  scan's inputs either: the expert scan reads each expert's blocks where
+  they lie in the stacked leaves.
 * **Heterogeneous blocks**: the block pattern interleaves attention and SSD
   layers (Gemma3 local:global, Zamba2 hybrid); Zamba2's shared attention
   block has ONE weight set applied once per repeat (weights live outside
@@ -151,9 +153,13 @@ def param_count(params) -> int:
 # forward (training / prefill math)
 # ---------------------------------------------------------------------------
 
-def _ffn_apply(cfg: ModelConfig, p: dict, x: jnp.ndarray) -> jnp.ndarray:
+def _ffn_apply(cfg: ModelConfig, p: dict, x: jnp.ndarray,
+               layer=None) -> jnp.ndarray:
+    """The layer's FFN.  With ``layer``, an MoE FFN's routed-expert leaves
+    are the layer-stacked ones (``decode_step``)."""
     if "router" in p:          # MoE params
-        return moe_forward(p, x, cfg.top_k, norm_topk_prob=cfg.norm_topk_prob)
+        return moe_forward(p, x, cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
+                           layer=layer)
     return mlp_forward(p, x)
 
 
@@ -360,6 +366,25 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 # decode step (serving)
 # ---------------------------------------------------------------------------
 
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def _split_experts(blocks: dict) -> Tuple[dict, dict]:
+    """The stacked block params without the MoE layers' routed-expert
+    leaves, and those leaves by layer name.  The layer scan slices the
+    first per layer; the second stay stacked for the expert scan to read
+    in place (a slice of them that is its operand would be copied out)."""
+    scanned, experts = {}, {}
+    for name, p in blocks.items():
+        ffn = p.get("ffn", {})
+        if "router" in ffn:
+            experts[name] = {k: ffn[k] for k in _EXPERT_LEAVES if k in ffn}
+            p = {**p, "ffn": {k: v for k, v in ffn.items()
+                              if k not in _EXPERT_LEAVES}}
+        scanned[name] = p
+    return scanned, experts
+
+
 def _put_layer(leaf: jnp.ndarray, new: jnp.ndarray, layer) -> jnp.ndarray:
     """``new`` as layer ``layer`` of the stacked leaf (in place), or as the
     unstacked leaf itself (``layer`` None), in the leaf's dtype."""
@@ -376,7 +401,9 @@ def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
     or with ``layer`` the layer scan's stacked leaves, of which layer
     ``layer`` is read and written in place: attention K/V and MLA's latent
     and rope key take one entry per slot, SSM leaves the layer's whole new
-    state; cross-attention K/V are read only."""
+    state; cross-attention K/V are read only.  With ``layer``, an MoE
+    FFN's routed-expert leaves in ``p`` are stacked too and read in place;
+    the rest of ``p`` is the layer's own."""
     new_lc = dict(lc)
     at = functools.partial(cache_layer, layer=layer)
     if spec.kind == "ssm":
@@ -426,7 +453,7 @@ def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
         out = jnp.einsum("bhqk,bkhd->bqhd", pattn, vr)
         x = x + out.reshape(B, 1, cfg.n_heads * hd) @ p["xattn"]["wo"]
     if cfg.ffn_kind != "none":
-        x = x + _ffn_apply(cfg, p["ffn"], rms_norm(x, p["norm2"]))
+        x = x + _ffn_apply(cfg, p["ffn"], rms_norm(x, p["norm2"]), layer)
     return x, new_lc
 
 
@@ -439,7 +466,10 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
     The layer scan carries the stacked cache and writes each layer's new
     entries into it in place (one per slot and leaf; an SSM layer's new
-    state); no layer's cache is copied out or restacked.  The serving
+    state); no layer's cache is copied out or restacked.  The MoE layers'
+    routed-expert leaves stay out of the scan's inputs: they reach the
+    expert scan stacked, with the layer index, and each expert's blocks
+    are read where they lie.  The serving
     engine donates the cache to the jitted step (``serving.engine.
     make_decode_step``), so the step updates the one cache buffer it is
     given and the caller's cache is consumed."""
@@ -460,14 +490,19 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             new_cache["prefix"].append(npc)
 
     shared = params.get("shared")
+    blocks, experts = _split_experts(params["blocks"])
 
     def block_body(carry, inp):
         x, cblk, sc = carry
         blk, layer = inp
         cblk = dict(cblk)
         for i, spec in enumerate(cfg.block_pattern):
-            x, cblk[f"l{i}"] = _layer_decode(cfg, spec, blk[f"l{i}"], x,
-                                             cblk[f"l{i}"], cache_len, layer)
+            name = f"l{i}"
+            p = blk[name]
+            if name in experts:
+                p = {**p, "ffn": {**p["ffn"], **experts[name]}}
+            x, cblk[name] = _layer_decode(cfg, spec, p, x, cblk[name],
+                                          cache_len, layer)
         if shared is not None:
             h = rms_norm(x, shared["norm1"])
             y, nk, nv = gqa_decode_step(
@@ -483,7 +518,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     n_scan = jax.tree.leaves(params["blocks"])[0].shape[0]
     (x, new_cache["blocks"], shared_cache), _ = jax.lax.scan(
         block_body, (x, cache["blocks"], cache.get("shared")),
-        (params["blocks"], jnp.arange(n_scan)))
+        (blocks, jnp.arange(n_scan)))
     if shared_cache is not None:
         new_cache["shared"] = shared_cache
 

@@ -13,6 +13,7 @@ from repro.launch.steps import make_train_step
 from repro.models import (decode_step, encdec_forward, encdec_prefill,
                           forward, init_cache, init_encdec_params,
                           init_params)
+from repro.models import transformer as T
 from repro.serving.engine import make_decode_step
 from repro.training.optimizer import adamw_init
 
@@ -94,6 +95,58 @@ def test_decode_matches_forward(arch):
     dec = jnp.stack(outs, axis=1).astype(jnp.float32)
     rel = float(jnp.abs(full - dec).max() / (jnp.abs(full).max() + 1e-9))
     assert rel < 0.05      # bf16 accumulation-order differences only
+
+
+def _layer_by_layer_step(params, cfg, tokens, cache):
+    """``decode_step``'s oracle for a one-layer block pattern: each layer
+    in turn on its own unstacked weights and cache, with no layer index,
+    so an MoE layer runs ``moe_forward`` on its (E, ...) expert leaves."""
+    (spec,) = cfg.block_pattern
+    at = lambda tree, r: jax.tree.map(lambda a: a[r], tree)
+    x, n = params["embed"][tokens], cache["len"]
+    new = {"len": n + 1}
+    if "prefix" in cache:
+        new["prefix"] = []
+        for blk, pc in zip(params["prefix"], cache["prefix"]):
+            x, lc = T._layer_decode(cfg, spec, blk["l0"], x, pc["l0"], n)
+            new["prefix"].append({"l0": lc})
+    stacked = cache["blocks"]["l0"]
+    for r in range(jax.tree.leaves(stacked)[0].shape[0]):
+        x, lc = T._layer_decode(cfg, spec, at(params["blocks"]["l0"], r), x,
+                                at(stacked, r), n)
+        stacked = jax.tree.map(lambda s, v: s.at[r].set(v), stacked, lc)
+    new["blocks"] = {"l0": stacked}
+    x = T.rms_norm(x, params["final_norm"])
+    head = params["embed"].T if cfg.tie_embeddings else params["head"]
+    return (x @ head)[:, 0, :], new
+
+
+@pytest.mark.parametrize("arch", ["mixtral_8x7b", "deepseek_v2_lite_16b"])
+def test_decode_step_reads_stacked_experts_in_place(arch):
+    """The decode step hands each MoE layer's routed experts to the expert
+    scan stacked, with the layer index (Mixtral's gates, then DeepSeek's
+    behind a dense first layer).  Over 4 steps at 2 slots its logits and
+    cache are those of running each layer on its own unstacked leaves;
+    3 scanned layers, each with weights of its own, so a wrong layer index
+    shows (float32, to rounding)."""
+    base = C.get_reduced(arch)
+    cfg = dataclasses.replace(base, block_repeat=base.first_k_dense + 3,
+                              dtype="float32")
+    params = init_params(RNG, cfg)
+    assert params["blocks"]["l0"]["ffn"]["w_up"].shape[:2] == (
+        3, cfg.n_routed)
+    toks = jax.random.randint(jax.random.PRNGKey(5), (4, 2, 1), 0,
+                              cfg.vocab_size)
+    cache = want_cache = init_cache(cfg, 2, max_len=16)
+    for t in range(toks.shape[0]):
+        got, cache = decode_step(params, cfg, toks[t], cache)
+        want, want_cache = _layer_by_layer_step(params, cfg, toks[t],
+                                                want_cache)
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=1e-5 * float(jnp.abs(want).max()))
+    for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(want_cache)):
+        np.testing.assert_allclose(a, b, rtol=0,
+                                   atol=1e-5 * float(jnp.abs(b).max()))
 
 
 # slot b takes PROMPT[b] tokens alone, then JOINT more beside the others;

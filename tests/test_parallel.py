@@ -103,6 +103,45 @@ print("sharded decode OK")
 """, devices=8)
 
 
+def test_served_moe_decode_step_sharded_matches_unsharded(subproc):
+    """Reduced DeepSeek (a dense layer, then 3 MoE layers of 8 experts) on
+    a (data 2, model 4) mesh: the experts shard over "model", so the step
+    takes the expert-sharded einsum on the layer-stacked expert leaves,
+    and its logits match the unsharded step (the expert scan) over 3
+    steps."""
+    subproc("""
+import dataclasses
+import jax, jax.numpy as jnp, numpy as np
+from repro import configs as C
+from repro.launch.mesh import make_mesh
+from repro.models import transformer as T
+from repro.parallel.sharding import cache_pspecs, param_pspecs, to_shardings
+from repro.serving.engine import make_decode_step
+
+cfg = dataclasses.replace(C.get_reduced("deepseek_v2_lite_16b"),
+                          dtype="float32", block_repeat=4)
+B, MAX_LEN, STEPS = 4, 32, 3
+params = T.init_params(jax.random.PRNGKey(0), cfg)
+cache = T.init_cache(cfg, B, MAX_LEN)
+toks = jax.random.randint(jax.random.PRNGKey(2), (STEPS, B, 1), 1,
+                          cfg.vocab_size)
+step = make_decode_step(cfg)
+mesh = make_mesh((2, 4), ("data", "model"))
+pspecs = param_pspecs(params, cfg, mesh)
+assert pspecs["blocks"]["l0"]["ffn"]["w_up"][1] == "model", pspecs
+ps = jax.device_put(params, to_shardings(pspecs, mesh))
+cs = jax.device_put(cache, to_shardings(cache_pspecs(cache, cfg, mesh),
+                                        mesh))
+for t in range(STEPS):
+    _, want, cache = step(params, toks[t], cache)
+    with jax.set_mesh(mesh):
+        _, got, cs = step(ps, toks[t], cs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+print("sharded moe decode OK")
+""", devices=8)
+
+
 def test_elastic_reshard_roundtrip(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np

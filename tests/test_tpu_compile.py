@@ -8,7 +8,9 @@ that do not fit the chip's 16 GB.  Here every Pallas kernel compiles with
 mamba2-2.7b's scan), and the serving engine's step (``make_decode_step``)
 for internlm2-1.8b FULL compiles at batch 8, max_len 2048 within the
 chip's memory, and at batch 16 updates its donated cache in place: the
-output aliases the whole cache, and no layer of it is copied out.
+output aliases the whole cache, and no layer of it is copied out.  The
+benchmark's deepseek-v2-lite-stage step at 32 x 2048 fits the chip and
+reads each MoE layer's expert stacks where they lie: none is copied out.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -19,6 +21,9 @@ The persistent compilation cache is off around these compiles (a program
 compiled for a described chip cannot be read back without one).
 """
 
+import importlib.util
+import json
+import pathlib
 import re
 
 import jax
@@ -37,6 +42,7 @@ from repro.kernels.ssd_scan.ssd_scan import ssd_scan_pallas
 from repro.models import transformer as T
 from repro.serving.engine import make_decode_step
 
+CHIP = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 LM = C.get_config("internlm2_1_8b")
 SSM = C.get_config("mamba2_2_7b")
 BATCH, MAX_LEN = 8, 2048
@@ -101,15 +107,16 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _engine_step(sharding, batch):
-    """The engine's donated step for internlm2-1.8b FULL at ``batch`` slots
-    of ``MAX_LEN``, compiled for the described chip; and its cache."""
-    params = jax.eval_shape(
-        lambda: T.init_params(jax.random.PRNGKey(0), LM))
-    cache = jax.eval_shape(lambda: T.init_cache(LM, batch, MAX_LEN))
+def _engine_step(sharding, batch, cfg=LM, make_params=None):
+    """The engine's donated step for ``cfg`` (internlm2-1.8b FULL) at
+    ``batch`` slots of ``MAX_LEN``, compiled for the described chip; and
+    its cache.  ``make_params`` builds the parameters (``init_params``)."""
+    params = jax.eval_shape(make_params or (
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = jax.eval_shape(lambda: T.init_cache(cfg, batch, MAX_LEN))
     place = lambda tree: jax.tree.map(
         lambda x: _spec(sharding, x.shape, x.dtype), tree)
-    compiled = make_decode_step(LM).lower(
+    compiled = make_decode_step(cfg).lower(
         place(params), _spec(sharding, (batch, 1), jnp.int32),
         place(cache)).compile()
     return compiled, cache
@@ -161,3 +168,37 @@ def test_engine_step_updates_the_donated_cache_in_place(one_chip):
     outputs = _top_level_outputs(compiled.as_text())
     assert shape(k.shape) in {t for _, t in outputs}   # the parse sees it
     assert not [o for o in outputs if o[1] == shape(k.shape[1:])]
+
+
+def _dsv2_stage():
+    """The benchmark's deepseek-v2-lite-stage: its program config and a
+    maker of its weights (``configs/deepseek-v2-lite-stage.py``)."""
+    path = CHIP / "configs" / "deepseek-v2-lite-stage.py"
+    spec = importlib.util.spec_from_file_location("dsv2_stage", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    raw = json.loads(path.with_suffix(".json").read_text())
+    return mod.program_config(raw), lambda: mod.make_weights(0, raw)
+
+
+def test_dsv2_step_reads_the_expert_stacks_in_place(one_chip):
+    """The DeepSeek stage's step at its cell's batch (32 slots of 2048)
+    fits the chip, and no instruction outside a fusion yields one layer's
+    (64, 2048, 1408) or (64, 1408, 2048) expert stack: the expert scan
+    reads each expert's block from the layer-stacked leaves where it lies,
+    instead of copying the layer's stacks out first."""
+    cfg, make_params = _dsv2_stage()
+    compiled, _ = _engine_step(one_chip, 32, cfg, make_params)
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 8e9        # bf16 weights + cache
+    assert held < TPU_V5E.hbm_bytes, held
+    E, d, f = cfg.n_routed, cfg.d_model, cfg.d_ff_expert
+    assert (E, d, f) == (64, 2048, 1408)
+    shape = lambda dims: "bf16[%s]" % ",".join(map(str, dims))
+    outputs = _top_level_outputs(compiled.as_text())
+    R = cfg.block_repeat - cfg.first_k_dense
+    assert shape((R, E, d, f)) in {t for _, t in outputs}  # the parse sees it
+    stacks = {shape((E, d, f)), shape((E, f, d))}
+    assert not [o for o in outputs if o[1] in stacks]
