@@ -28,6 +28,7 @@ ARCHS: List[str] = [
     "mamba2_2_7b",
     "zamba2_7b",
     "seamless_m4t_large_v2",
+    "granite_4_0_h_micro",
 ]
 
 # canonical ids as given in the assignment -> module names
@@ -42,6 +43,7 @@ ALIASES = {
     "mamba2-2.7b": "mamba2_2_7b",
     "zamba2-7b": "zamba2_7b",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

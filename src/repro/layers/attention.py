@@ -92,12 +92,11 @@ def _tile_mask(q_pos, k_pos, causal: bool, window, Skv: int):
 
 
 def _blockwise_fwd_impl(q, k, v, causal, window, q_block, kv_block,
-                        q_offset, skv_true):
+                        q_offset, skv_true, scale):
     """Returns (out (B,Sq_p,Hq,Dv), lse (B,Hq,Sq_p)) on PADDED lengths."""
     B, Sq_p, Hq, D = q.shape
     _, Skv_p, Hkv, Dv = v.shape
     rep = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
     qb, kb = q_block, kv_block
     nq, nk = Sq_p // qb, Skv_p // kb
 
@@ -149,30 +148,29 @@ def _blockwise_fwd_impl(q, k, v, causal, window, q_block, kv_block,
 import functools as _ft
 
 
-@_ft.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@_ft.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _blockwise_attention(q, k, v, causal, window, q_block, kv_block,
-                         q_offset, skv_true):
+                         q_offset, skv_true, scale):
     out, _ = _blockwise_fwd_impl(q, k, v, causal, window, q_block,
-                                 kv_block, q_offset, skv_true)
+                                 kv_block, q_offset, skv_true, scale)
     return out
 
 
 def _bw_fwd(q, k, v, causal, window, q_block, kv_block, q_offset,
-            skv_true):
+            skv_true, scale):
     out, lse = _blockwise_fwd_impl(q, k, v, causal, window, q_block,
-                                   kv_block, q_offset, skv_true)
+                                   kv_block, q_offset, skv_true, scale)
     return out, (q, k, v, out, lse)
 
 
-def _bw_bwd(causal, window, q_block, kv_block, q_offset, skv_true, res,
-            dout):
+def _bw_bwd(causal, window, q_block, kv_block, q_offset, skv_true, scale,
+            res, dout):
     """Flash backward: recompute p per tile from the saved LSE — O(S)
     memory instead of autodiff-through-scan's O(S^2 / block) residuals."""
     q, k, v, out, lse = res
     B, Sq_p, Hq, D = q.shape
     _, Skv_p, Hkv, Dv = v.shape
     rep = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
     qb, kb = q_block, kv_block
     nq, nk = Sq_p // qb, Skv_p // kb
     Skv_true = skv_true
@@ -244,12 +242,14 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         *, causal: bool = True,
                         window: Optional[int] = None,
                         q_block: int = 512, kv_block: int = 1024,
-                        q_offset: int = 0) -> jnp.ndarray:
+                        q_offset: int = 0,
+                        scale: Optional[float] = None) -> jnp.ndarray:
     """Online-softmax attention without materializing S_q x S_kv scores.
 
     q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D) with Hq % Hkv == 0 (GQA).
     ``q_offset``: absolute position of q[:, 0] relative to k[:, 0]
     (prefill: Skv - Sq when a prefix cache exists; 0 otherwise).
+    ``scale``: the scores' scale before the softmax (None: 1/sqrt(D)).
     Returns (B, Sq, Hq, Dv).
 
     Differentiable via a flash-style custom VJP (recompute-from-LSE), so
@@ -267,8 +267,9 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if Skv_p != Skv:
         k = jnp.pad(k, ((0, 0), (0, Skv_p - Skv), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, Skv_p - Skv), (0, 0), (0, 0)))
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     out = _blockwise_attention(q, k, v, causal, window, qb, kb, q_offset,
-                               Skv)
+                               Skv, scale)
     return out[:, :Sq]
 
 
@@ -294,14 +295,17 @@ def _project_qkv(params: dict, x: jnp.ndarray, n_heads: int,
 def gqa_attention(params: dict, x: jnp.ndarray, positions: jnp.ndarray,
                   *, n_heads: int, n_kv_heads: int, head_dim: int,
                   window: Optional[int] = None, rope: str = "rope",
-                  rope_theta: float = 10000.0) -> jnp.ndarray:
-    """Full-sequence GQA (training / prefill).  x: (B, S, d_model)."""
+                  rope_theta: float = 10000.0,
+                  scale: Optional[float] = None) -> jnp.ndarray:
+    """Full-sequence GQA (training / prefill).  x: (B, S, d_model);
+    ``scale``: the softmax scale (None: 1/sqrt(head_dim))."""
     q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
     if rope == "rope":
         q, k = apply_rope(q, k, positions, rope_theta)
     elif rope == "mrope":
         q, k = apply_mrope(q, k, positions, theta=rope_theta)
-    out = blockwise_attention(q, k, v, causal=True, window=window)
+    out = blockwise_attention(q, k, v, causal=True, window=window,
+                              scale=scale)
     B, S = x.shape[:2]
     return out.reshape(B, S, n_heads * head_dim) @ params["wo"]
 
@@ -330,12 +334,14 @@ def gqa_decode_step(params: dict, x: jnp.ndarray, cache_k: jnp.ndarray,
                     cache_v: jnp.ndarray, cache_len: jnp.ndarray,
                     *, n_heads: int, n_kv_heads: int, head_dim: int,
                     window: Optional[int] = None, rope: str = "rope",
-                    rope_theta: float = 10000.0, layer=None
+                    rope_theta: float = 10000.0, layer=None,
+                    scale: Optional[float] = None
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step.  x: (B, 1, d_model); cache_k/v: (B, Smax, Hkv, D),
     or with ``layer`` (a scalar index) the stacked (R, B, Smax, Hkv, D)
     caches of a layer scan, whose layer ``layer`` the step writes and
-    reads in place; cache_len: (B,) ABSOLUTE sequence lengths so far.
+    reads in place; cache_len: (B,) ABSOLUTE sequence lengths so far;
+    ``scale``: the softmax scale (None: 1/sqrt(head_dim)).
 
     Sliding-window layers use a RING cache: allocate Smax == window + 1 and
     the ring then holds exactly the last `window`+1 tokens — K entries are
@@ -372,7 +378,8 @@ def gqa_decode_step(params: dict, x: jnp.ndarray, cache_k: jnp.ndarray,
     from .hints import data_axis_names, shard_hint
     daxes = data_axis_names() or None
     g = n_heads // n_kv_heads
-    scale = 1.0 / math.sqrt(head_dim)
+    if scale is None:
+        scale = 1.0 / math.sqrt(head_dim)
     qg = q.reshape(B, 1, n_kv_heads, g, head_dim)
     kc = shard_hint(cache_layer(cache_k, layer).astype(q.dtype), daxes,
                     "model", None, None)

@@ -170,15 +170,26 @@ def mamba2_forward(params: dict, x: jnp.ndarray, *, d_inner: int,
     return (y @ params["w_out"]).astype(x.dtype)
 
 
+@jax.named_scope("ssm.decode")
 def mamba2_decode_step(params: dict, x: jnp.ndarray,
                        ssm_state: jnp.ndarray, conv_state: dict,
                        *, d_inner: int, d_state: int, n_heads: int,
-                       n_groups: int = 1
+                       n_groups: int = 1, advance: jnp.ndarray,
+                       reset: jnp.ndarray
                        ) -> Tuple[jnp.ndarray, jnp.ndarray, dict]:
     """One decode step — O(1) in context length (the SSM serving win).
 
     x: (B, 1, d_model); ssm_state: (B, H, P, N) fp32;
     conv_state: {"x": (B, K-1, d_inner), "bc": (B, K-1, 2*G*N)}.
+
+    Each slot's state is its own.  ``reset`` (B,) bool marks the slots
+    whose state so far is taken as zero: a slot whose prompt replay
+    starts (its cache length is 0), so a reused slot never inherits its
+    last occupant's state.  ``advance`` (B,) bool marks the slots the step
+    advances, those fed a real token; every other slot's state and conv
+    windows come back as they were given.  The projections, the conv
+    windows, the state update and readout and the gated norm run under
+    the ``ssm.decode`` scope.
     """
     from .norms import rms_norm
     B = x.shape[0]
@@ -186,6 +197,10 @@ def mamba2_decode_step(params: dict, x: jnp.ndarray,
     gn = n_groups * d_state
     K = params["conv_x"].shape[0]
     z, xi, bc, dt = _project(params, x, d_state, n_groups, n_heads)
+    given = (ssm_state, conv_state)
+    ssm_state = jnp.where(reset[:, None, None, None], 0.0, ssm_state)
+    conv_state = {k: jnp.where(reset[:, None, None], 0, v)
+                  for k, v in conv_state.items()}
 
     def conv_step(state, new, w, bias):
         win = jnp.concatenate([state, new], axis=1)        # (B, K, C)
@@ -210,5 +225,7 @@ def mamba2_decode_step(params: dict, x: jnp.ndarray,
     y = y + xh.astype(jnp.float32) * params["d_skip"][None, :, None]
     y = y.reshape(B, 1, d_inner).astype(x.dtype)
     y = rms_norm(y * jax.nn.silu(z), params["norm_w"])
-    return ((y @ params["w_out"]).astype(x.dtype), new_state,
-            {"x": ncx, "bc": ncb})
+    new_state = jnp.where(advance[:, None, None, None], new_state, given[0])
+    new_conv = {k: jnp.where(advance[:, None, None], v, given[1][k])
+                for k, v in {"x": ncx, "bc": ncb}.items()}
+    return (y @ params["w_out"]).astype(x.dtype), new_state, new_conv

@@ -3,10 +3,20 @@
 One ``ModelConfig`` describes every assigned architecture: dense GQA
 transformers, MoE (Mixtral / DeepSeek-MLA), sliding-window + local:global
 patterns (Gemma3), M-RoPE VLM backbones (Qwen2-VL), pure SSM (Mamba2),
-hybrid SSM+shared-attention (Zamba2), and encoder-decoder audio backbones
-(Seamless-M4T).  The block pattern mirrors the Transformer IR's
-block-of-cells structure (core/ir.py) — ``to_ir()`` is the IR converter for
-zoo models.
+hybrid SSM+shared-attention (Zamba2), hybrid Mamba-2 / NoPE attention
+stacks with an MLP after every layer (Granite-4.0-H), and encoder-decoder
+audio backbones (Seamless-M4T).  The block pattern mirrors the Transformer
+IR's block-of-cells structure (core/ir.py) — ``to_ir()`` is the IR
+converter for zoo models.
+
+Every layer, attention or SSM, is followed by the configuration's FFN
+unless ``ffn_kind`` is "none" (Mamba2 and Zamba2's mixers stand alone).
+The muP scalars (Granite) default to the identity and are applied only
+where they differ from it: ``embedding_multiplier`` scales the token
+embeddings, ``residual_multiplier`` each sublayer's output (mixer,
+attention and FFN alike) before it joins the residual stream,
+``logits_scaling`` divides the logits, and ``attn_scale`` replaces
+attention's softmax scale ``1/sqrt(head_dim)``.
 """
 
 from __future__ import annotations
@@ -93,6 +103,11 @@ class ModelConfig:
     shared_d_ff: int = 0
     # embeddings / head
     tie_embeddings: bool = False
+    # muP scalars (Granite); the defaults are the identity
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0        # logits are divided by it
+    attn_scale: Optional[float] = None     # None: 1 / sqrt(head_dim)
     # encoder-decoder
     encoder: Optional[EncoderConfig] = None
     cross_attn: bool = False           # decoder layers attend to enc memory
@@ -115,6 +130,10 @@ class ModelConfig:
         return n
 
     @property
+    def has_ssm(self) -> bool:
+        return any(s.kind == "ssm" for s in self.block_pattern)
+
+    @property
     def windows(self) -> tuple:
         return tuple(sorted({s.window for s in self.block_pattern
                              if s.kind == "attn"},
@@ -130,7 +149,7 @@ class ModelConfig:
             raise ValueError("rope_scaling (YaRN) is implemented for MLA only")
         if self.ffn_kind == "moe" and (not self.n_routed or not self.top_k):
             raise ValueError("moe config incomplete")
-        if any(s.kind == "ssm" for s in self.block_pattern):
+        if self.has_ssm:
             if not (self.d_inner and self.d_state and self.n_ssd_heads):
                 raise ValueError("ssm config incomplete")
             if self.d_inner % self.n_ssd_heads:
@@ -150,8 +169,7 @@ class ModelConfig:
                     d_inner=self.d_inner, d_state=self.d_state,
                     n_ssd_heads=self.n_ssd_heads, d_conv=self.d_conv,
                     n_groups=self.n_ssm_groups))
-                continue
-            if self.attn_kind == "mla":
+            elif self.attn_kind == "mla":
                 cells.append(IR.MLACell(
                     name=f"mla{i}", d_model=self.d_model,
                     n_heads=self.n_heads, kv_lora_rank=self.kv_lora_rank,
@@ -165,7 +183,7 @@ class ModelConfig:
                     head_dim=self.resolved_head_dim,
                     qkv_bias=self.qkv_bias, window=spec.window,
                     rope=self.rope))
-            if self.cross_attn:
+            if self.cross_attn and spec.kind != "ssm":
                 cells.append(IR.CrossAttentionCell(
                     name=f"xattn{i}", d_model=self.d_model,
                     n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,

@@ -22,9 +22,10 @@ Design notes
   scan's inputs either: the expert scan reads each expert's blocks where
   they lie in the stacked leaves.
 * **Heterogeneous blocks**: the block pattern interleaves attention and SSD
-  layers (Gemma3 local:global, Zamba2 hybrid); Zamba2's shared attention
-  block has ONE weight set applied once per repeat (weights live outside
-  the scanned pytree).
+  layers (Gemma3 local:global, Zamba2 and Granite-4.0-H hybrids); every
+  layer is followed by the FFN unless ``ffn_kind`` is "none"; Zamba2's
+  shared attention block has ONE weight set applied once per repeat
+  (weights live outside the scanned pytree).
 """
 
 from __future__ import annotations
@@ -68,8 +69,7 @@ def _init_layer(rng, cfg: ModelConfig, spec: LayerSpec,
         p["mixer"] = init_mamba2(k1, cfg.d_model, cfg.d_inner, cfg.d_state,
                                  cfg.n_ssd_heads, cfg.d_conv,
                                  cfg.n_ssm_groups, dtype=dt)
-        return p
-    if cfg.attn_kind == "mla":
+    elif cfg.attn_kind == "mla":
         p["attn"] = init_mla(k1, cfg.d_model, cfg.n_heads, cfg.kv_lora_rank,
                              cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                              cfg.v_head_dim, dtype=dt)
@@ -77,7 +77,7 @@ def _init_layer(rng, cfg: ModelConfig, spec: LayerSpec,
         p["attn"] = init_attention(k1, cfg.d_model, cfg.n_heads,
                                    cfg.n_kv_heads, cfg.resolved_head_dim,
                                    cfg.qkv_bias, dtype=dt)
-    if cfg.cross_attn:
+    if cfg.cross_attn and spec.kind != "ssm":
         p["xattn"] = init_attention(k2, cfg.d_model, cfg.n_heads,
                                     cfg.n_kv_heads, cfg.resolved_head_dim,
                                     dtype=dt)
@@ -163,14 +163,55 @@ def _ffn_apply(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     return mlp_forward(p, x)
 
 
+def _residual(cfg: ModelConfig, x: jnp.ndarray, y: jnp.ndarray
+              ) -> jnp.ndarray:
+    """The residual stream ``x`` plus a sublayer's output ``y``, scaled by
+    the residual multiplier where it is not 1."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
+def _ffn_sublayer(cfg: ModelConfig, p: dict, x: jnp.ndarray,
+                  layer=None) -> jnp.ndarray:
+    """``x`` through the layer's FFN sublayer (pre-norm ``norm2``, then the
+    residual), which follows every layer, SSM or attention, unless
+    ``ffn_kind`` is "none"."""
+    if cfg.ffn_kind == "none":
+        return x
+    return _residual(cfg, x, _ffn_apply(cfg, p["ffn"],
+                                        rms_norm(x, p["norm2"]), layer))
+
+
+def _embed(params: dict, cfg: ModelConfig, tokens, embeds) -> jnp.ndarray:
+    """Token embeddings (or the given ``embeds``), times the embedding
+    multiplier where it is not 1."""
+    x = params["embed"][tokens] if embeds is None \
+        else embeds.astype(_dtype(cfg))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _final_hidden(params: dict, cfg: ModelConfig,
+                  x: jnp.ndarray) -> jnp.ndarray:
+    """The final norm, divided by ``logits_scaling`` where it is not 1: its
+    product with the head is the logits."""
+    x = rms_norm(x, params["final_norm"])
+    if cfg.logits_scaling != 1.0:
+        x = x / cfg.logits_scaling
+    return x
+
+
 def _layer_apply(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
                  positions: jnp.ndarray,
                  enc_memory: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     if spec.kind == "ssm":
-        return x + mamba2_forward(p["mixer"], rms_norm(x, p["norm1"]),
-                                  d_inner=cfg.d_inner, d_state=cfg.d_state,
-                                  n_heads=cfg.n_ssd_heads,
-                                  n_groups=cfg.n_ssm_groups)
+        x = _residual(cfg, x, mamba2_forward(
+            p["mixer"], rms_norm(x, p["norm1"]), d_inner=cfg.d_inner,
+            d_state=cfg.d_state, n_heads=cfg.n_ssd_heads,
+            n_groups=cfg.n_ssm_groups))
+        return _ffn_sublayer(cfg, p, x)
     h = rms_norm(x, p["norm1"])
     # Archs whose head count doesn't divide the TP axis (qwen2-0.5b: 14,
     # qwen1.5-32b: 40, qwen2-vl: 28) keep attention projections replicated;
@@ -198,10 +239,11 @@ def _layer_apply(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
                              n_kv_heads=cfg.n_kv_heads,
                              head_dim=cfg.resolved_head_dim,
                              window=spec.window, rope=cfg.rope,
-                             rope_theta=cfg.rope_theta)
+                             rope_theta=cfg.rope_theta,
+                             scale=cfg.attn_scale)
     if reshard:
         attn = shard_hint(attn, data_axis_names() or None, None, None)
-    x = x + attn
+    x = _residual(cfg, x, attn)
     if cfg.cross_attn and enc_memory is not None:
         hx = rms_norm(x, p["norm_x"])
         B, S, _ = hx.shape
@@ -212,9 +254,7 @@ def _layer_apply(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
         v = (enc_memory @ p["xattn"]["wv"]).reshape(B, Se, cfg.n_kv_heads, hd)
         out = blockwise_attention(q, k, v, causal=False)
         x = x + out.reshape(B, S, cfg.n_heads * hd) @ p["xattn"]["wo"]
-    if cfg.ffn_kind != "none":
-        x = x + _ffn_apply(cfg, p["ffn"], rms_norm(x, p["norm2"]))
-    return x
+    return _ffn_sublayer(cfg, p, x)
 
 
 def _shared_apply(cfg: ModelConfig, shared: dict,
@@ -240,13 +280,12 @@ def forward(params: dict, cfg: ModelConfig,
     modality frontends (VLM patches / audio frames).
     ``positions``: (B, S) or (B, S, 3) for M-RoPE; defaults to arange.
     ``remat``: activation-checkpoint each block (training memory policy).
-    ``return_hidden``: return final-norm hidden states instead of logits
-    (lets the trainer chunk the LM-head matmul + loss over the sequence).
+    ``return_hidden``: return final-norm hidden states (divided by
+    ``logits_scaling``) instead of logits: their product with the head is
+    the logits (lets the trainer chunk the LM-head matmul + loss over the
+    sequence).
     """
-    if embeds is None:
-        x = params["embed"][tokens]
-    else:
-        x = embeds.astype(_dtype(cfg))
+    x = _embed(params, cfg, tokens, embeds)
     B, S = x.shape[:2]
     if positions is None:
         pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
@@ -281,7 +320,7 @@ def forward(params: dict, cfg: ModelConfig,
 
     body = jax.checkpoint(block_body) if remat else block_body
     x, _ = jax.lax.scan(body, x, params["blocks"])
-    x = rms_norm(x, params["final_norm"])
+    x = _final_hidden(params, cfg, x)
     if return_hidden:
         return x
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
@@ -297,7 +336,16 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     """All-zero cache pytree in the model's dtype.  Layout per scanned
     repeat (leading R axis): attention -> k/v (R, B, Smax, Hkv, D); MLA ->
     latent + rope-key; SSM -> fp32 state + conv window.  ``len``: (B,)
-    valid lengths."""
+    valid lengths.
+
+    A cache that holds SSM state also carries ``advance``: (B,) bool, the
+    slots a decode step advances (all of them here).  A step changes a
+    slot's ``ssm``, ``conv_x`` and ``conv_bc`` only where ``advance`` is
+    set, and takes a slot's state so far as zero where its ``len`` is 0,
+    so a reused slot starts its replay from zero state.  The serving
+    engine uploads ``advance`` beside ``len``: the replaying slot during a
+    prompt replay, the active slots otherwise.  Caches with no SSM layer
+    carry no ``advance``."""
     dt = _dtype(cfg)
     R = cfg.block_repeat - cfg.first_k_dense
     hd = cfg.resolved_head_dim
@@ -347,6 +395,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                    for i, spec in enumerate(cfg.block_pattern)},
         "len": jnp.zeros((batch,), jnp.int32),
     }
+    if cfg.has_ssm:
+        cache["advance"] = jnp.ones((batch,), bool)
     if cfg.first_k_dense:
         cache["prefix"] = [
             {f"l{i}": layer_cache(spec, lead=())
@@ -395,15 +445,20 @@ def _put_layer(leaf: jnp.ndarray, new: jnp.ndarray, layer) -> jnp.ndarray:
 
 
 def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
-                  lc: dict, cache_len: jnp.ndarray,
-                  layer=None) -> Tuple[jnp.ndarray, dict]:
+                  lc: dict, cache_len: jnp.ndarray, layer=None,
+                  advance=None) -> Tuple[jnp.ndarray, dict]:
     """One layer of the decode step.  ``lc`` holds the layer's cache leaves,
     or with ``layer`` the layer scan's stacked leaves, of which layer
     ``layer`` is read and written in place: attention K/V and MLA's latent
     and rope key take one entry per slot, SSM leaves the layer's whole new
     state; cross-attention K/V are read only.  With ``layer``, an MoE
     FFN's routed-expert leaves in ``p`` are stacked too and read in place;
-    the rest of ``p`` is the layer's own."""
+    the rest of ``p`` is the layer's own.
+
+    An SSM layer advances the state of the slots in ``advance`` (the
+    cache's (B,) mask, which an SSM layer needs) and leaves every other
+    slot's as it was; a slot whose ``cache_len`` is 0 starts from zero
+    state.  Every layer's FFN sublayer follows its mixer or attention."""
     new_lc = dict(lc)
     at = functools.partial(cache_layer, layer=layer)
     if spec.kind == "ssm":
@@ -412,11 +467,12 @@ def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
             p["mixer"], h, at(lc["ssm"]),
             {"x": at(lc["conv_x"]), "bc": at(lc["conv_bc"])},
             d_inner=cfg.d_inner, d_state=cfg.d_state,
-            n_heads=cfg.n_ssd_heads, n_groups=cfg.n_ssm_groups)
+            n_heads=cfg.n_ssd_heads, n_groups=cfg.n_ssm_groups,
+            advance=advance, reset=cache_len == 0)
         new_lc["ssm"] = _put_layer(lc["ssm"], st, layer)
         new_lc["conv_x"] = _put_layer(lc["conv_x"], cv["x"], layer)
         new_lc["conv_bc"] = _put_layer(lc["conv_bc"], cv["bc"], layer)
-        return x + y, new_lc
+        return _ffn_sublayer(cfg, p, _residual(cfg, x, y), layer), new_lc
     h = rms_norm(x, p["norm1"])
     if cfg.attn_kind == "mla":
         y, cc, ck = mla_decode_step(p["attn"], h, lc["c_kv"], lc["k_pe"],
@@ -435,9 +491,10 @@ def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
             p["attn"], h, lc["k"], lc["v"], cache_len,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.resolved_head_dim, window=spec.window,
-            rope=cfg.rope, rope_theta=cfg.rope_theta, layer=layer)
+            rope=cfg.rope, rope_theta=cfg.rope_theta, layer=layer,
+            scale=cfg.attn_scale)
         new_lc["k"], new_lc["v"] = ck, cv
-    x = x + y
+    x = _residual(cfg, x, y)
     if cfg.cross_attn and "xk" in lc:
         hx = rms_norm(x, p["norm_x"])
         B = hx.shape[0]
@@ -452,9 +509,7 @@ def _layer_decode(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jnp.ndarray,
         pattn = jax.nn.softmax(s, axis=-1).astype(vr.dtype)
         out = jnp.einsum("bhqk,bkhd->bqhd", pattn, vr)
         x = x + out.reshape(B, 1, cfg.n_heads * hd) @ p["xattn"]["wo"]
-    if cfg.ffn_kind != "none":
-        x = x + _ffn_apply(cfg, p["ffn"], rms_norm(x, p["norm2"]), layer)
-    return x, new_lc
+    return _ffn_sublayer(cfg, p, x, layer), new_lc
 
 
 def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -466,19 +521,21 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
     The layer scan carries the stacked cache and writes each layer's new
     entries into it in place (one per slot and leaf; an SSM layer's new
-    state); no layer's cache is copied out or restacked.  The MoE layers'
-    routed-expert leaves stay out of the scan's inputs: they reach the
-    expert scan stacked, with the layer index, and each expert's blocks
-    are read where they lie.  The serving
-    engine donates the cache to the jitted step (``serving.engine.
-    make_decode_step``), so the step updates the one cache buffer it is
-    given and the caller's cache is consumed."""
-    if embeds is None:
-        x = params["embed"][tokens]
-    else:
-        x = embeds.astype(_dtype(cfg))
+    state); no layer's cache is copied out or restacked.  An SSM layer
+    advances only the slots in the cache's ``advance`` mask, which the
+    step passes on unchanged, and starts a slot whose ``len`` is 0 from
+    zero state (``init_cache``).  The MoE layers' routed-expert leaves
+    stay out of the scan's inputs: they reach the expert scan stacked,
+    with the layer index, and each expert's blocks are read where they
+    lie.  The serving engine donates the cache to the jitted step
+    (``serving.engine.make_decode_step``), so the step updates the one
+    cache buffer it is given and the caller's cache is consumed."""
+    x = _embed(params, cfg, tokens, embeds)
     cache_len = cache["len"]
     new_cache = {"len": cache_len + 1}
+    advance = cache.get("advance")
+    if advance is not None:
+        new_cache["advance"] = advance
 
     if "prefix" in cache:
         new_cache["prefix"] = []
@@ -486,7 +543,8 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             npc = {}
             for i, spec in enumerate(cfg.block_pattern):
                 x, npc[f"l{i}"] = _layer_decode(cfg, spec, blk[f"l{i}"], x,
-                                                pc[f"l{i}"], cache_len)
+                                                pc[f"l{i}"], cache_len,
+                                                advance=advance)
             new_cache["prefix"].append(npc)
 
     shared = params.get("shared")
@@ -502,7 +560,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             if name in experts:
                 p = {**p, "ffn": {**p["ffn"], **experts[name]}}
             x, cblk[name] = _layer_decode(cfg, spec, p, x, cblk[name],
-                                          cache_len, layer)
+                                          cache_len, layer, advance)
         if shared is not None:
             h = rms_norm(x, shared["norm1"])
             y, nk, nv = gqa_decode_step(
@@ -522,6 +580,6 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     if shared_cache is not None:
         new_cache["shared"] = shared_cache
 
-    x = rms_norm(x, params["final_norm"])
+    x = _final_hidden(params, cfg, x)
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
     return (x @ head)[:, 0, :], new_cache

@@ -10,6 +10,11 @@ Implements iteration-level batching over a slot-based KV cache:
   * each engine iteration runs ONE jitted decode step over all slots
     (inactive slots are masked); ``_prefill_slot``, the one prefill,
     populates a request's slot by replaying its prompt through that step;
+  * each slot's recurrent (SSM) state is its own: the host uploads which
+    slots a step advances (``advance``, beside the lengths) so that a
+    replay or a decode iteration changes only the slots it feeds, and a
+    slot whose replay starts has length 0, which the step reads as zero
+    state (``transformer.init_cache``);
   * the step is given the cache to consume (``make_decode_step`` donates
     it): it writes each slot's new entry in place and returns the one
     cache buffer, so the engine holds the cache once and drops its
@@ -117,6 +122,11 @@ class ServingEngine:
         self.kv_budget = kv_token_budget or (max_batch * max_len)
         self.slots = [_Slot() for _ in range(max_batch)]
         self.cache = T.init_cache(cfg, max_batch, max_len)
+        # bytes of recurrent (SSM) state the cache holds, for all slots
+        self.state_reserved = sum(
+            x.size * x.dtype.itemsize
+            for path, x in jax.tree_util.tree_leaves_with_path(self.cache)
+            if path[-1].key in ("ssm", "conv_x", "conv_bc"))
         self.queue: List[dict] = []
         self._order = 0
         self.preemptions = 0
@@ -174,11 +184,18 @@ class ServingEngine:
 
     def _prefill_slot(self, i: int) -> None:
         """Replay the prompt through the jitted decode step (correctness-
-        first prefill; the whole batch's other slots ride along masked)."""
+        first prefill; the whole batch's other slots ride along masked).
+
+        The replay starts slot ``i`` at length 0, so its SSM state (if the
+        model has any) starts from zero whatever the slot held before, and
+        advances only slot ``i``: the other slots' states stand still, and
+        their attention lengths are held back.  At the end every active
+        slot, ``i`` included, is advanced again."""
         s = self.slots[i]
         tr = self.trace
         with OFF if tr is None else tr.span("engine.prefill.sync"):
             self.cache["len"] = self._lengths(replaying=(i, 0))
+            self._set_advance(only=i)
         for t in range(len(s.prompt)):
             toks = np.zeros((self.max_batch, 1), np.int32)
             toks[i, 0] = s.prompt[t]
@@ -190,6 +207,7 @@ class ServingEngine:
                 logits_tok.block_until_ready()
         s.generated = 1
         with OFF if tr is None else tr.span("engine.prefill.sync"):
+            self._set_advance()
             first = int(jax.device_get(logits_tok)[i])
             s.first_logits = np.asarray(jax.device_get(logits[i]),
                                         np.float32)
@@ -236,7 +254,8 @@ class ServingEngine:
             with OFF if tr is None else tr.span(
                     "engine.decode", active=len(active),
                     kv_tokens=self._kv_used(),
-                    kv_reserved=self.max_batch * self.max_len):
+                    kv_reserved=self.max_batch * self.max_len,
+                    state_reserved=self.state_reserved):
                 now = self._decode_iteration(active, now, records)
             iters += 1
 
@@ -278,7 +297,17 @@ class ServingEngine:
             # the step advanced every slot; an idle one must not grow
             if not all(s.active for s in self.slots):
                 self.cache["len"] = self._lengths()
+                self._set_advance()
         return now
+
+    def _set_advance(self, only: Optional[int] = None) -> None:
+        """Upload which slots the next steps advance, for a cache that
+        holds SSM state: slot ``only`` (a replay), else the active slots."""
+        if "advance" not in self.cache:
+            return
+        adv = np.array([s.active for s in self.slots]) if only is None \
+            else np.arange(self.max_batch) == only
+        self.cache["advance"] = jnp.asarray(adv)
 
     def _lengths(self, replaying=None) -> jnp.ndarray:
         """Every slot's cache length as the host knows it, uploaded: an

@@ -25,22 +25,28 @@ in place (``engine.make_decode_step``), kept every span name and attr.
 ``engine.prefill.sync``  the replay's host syncs: the length reset, the
                          per-token length fix-up (an upload of the
                          lengths the host keeps) and wait on the step,
-                         the first token and logits fetch
+                         the first token and logits fetch; with SSM
+                         state, the uploads of the slots the steps
+                         advance at the replay's start and end
 ``engine.decode``        one decode iteration; ``active`` slots,
                          ``kv_tokens`` in use over all slots,
-                         ``kv_reserved`` (``max_batch * max_len``)
+                         ``kv_reserved`` (``max_batch * max_len``),
+                         ``state_reserved``: bytes of recurrent (SSM)
+                         state and conv windows the cache holds for all
+                         slots (0 without SSM layers)
 ``engine.decode.call``   the step call and its tokens' fetch: the host
                          waiting on the device
 ``engine.decode.sync``   the length fix-up: one upload of the lengths
-                         the host keeps, when a slot is idle (a full
-                         batch needs none)
+                         the host keeps (and, with SSM state, of the
+                         slots the next step advances), when a slot is
+                         idle (a full batch needs none)
 ``engine.evict``         one preemption
 =======================  ===================================================
 
 Each span and attr has a reader: ``summary`` here, an operator's line.
 
-Inside the jitted step, the MLA and MoE layers put their device ops under
-``jax.named_scope`` scopes.  The compiled step carries the scope in each
+Inside the jitted step, the MLA, MoE and SSM layers put their device ops
+under ``jax.named_scope`` scopes.  The compiled step carries the scope in each
 instruction's ``op_name`` metadata (``.../<scope>/...``); a profiler
 trace names the instruction, so a device op's scope is read from the
 compiled step.  They are an interface too, kept under these names by a
@@ -56,6 +62,11 @@ later rebuild of the layer:
 ``moe.experts``    ``layers/moe.py``: the routed experts, weighted by their
                    gates
 ``moe.shared``     ``layers/moe.py``: the shared experts on every token
+``ssm.decode``     ``layers/ssm.py`` ``mamba2_decode_step``: the x, z and
+                   B/C/dt projections, the conv windows, the state update
+                   and readout (each slot's state advanced or kept, and
+                   zeroed where a replay starts), the gated norm and
+                   ``w_out``
 =================  =========================================================
 
 No reader in the repository reads them yet; ``summary()`` does not.

@@ -3,6 +3,7 @@ import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -59,3 +60,39 @@ def assert_report_matches(rep, want):
 def report_matches():
     """Golden-report comparison (exact except the means, see above)."""
     return assert_report_matches
+
+
+def engine_rows(eng, requests):
+    """Serve ``requests`` on the ServingEngine ``eng``: every row of logits
+    its step computes for a request it serves (prompt replay, then
+    decoding), keyed (rid, position of the fed token), and the report's
+    results."""
+    step, prefill = eng._decode, eng._prefill_slot
+    replaying, rows = [], {}
+
+    def prefill_slot(i):
+        replaying.append(i)
+        try:
+            prefill(i)
+        finally:
+            replaying.pop()
+
+    def decode(p, toks, cache):
+        lens = np.array(cache["len"])       # the step consumes the cache
+        nxt, logits, new = step(p, toks, cache)
+        slots = replaying[-1:] or [i for i, s in enumerate(eng.slots)
+                                   if s.active]
+        for i in slots:
+            rows[eng.slots[i].rid, int(lens[i])] = np.asarray(logits[i])
+        return nxt, logits, new
+
+    eng._prefill_slot, eng._decode = prefill_slot, decode
+    try:
+        return rows, eng.run(requests).results
+    finally:
+        eng._prefill_slot, eng._decode = prefill, step
+
+
+@pytest.fixture
+def served_rows():
+    return engine_rows

@@ -97,8 +97,33 @@ def test_decode_spans_count_iterations_and_kv_in_use(traced):
         assert s.attrs["kv_reserved"] == 3 * 64
         assert 0 < s.attrs["kv_tokens"] <= s.attrs["kv_reserved"]
         assert 1 <= s.attrs["active"] <= 3
+        assert s.attrs["state_reserved"] == 0          # no SSM layer
     line = telemetry.summary(spans)
     assert line.startswith(f"{report.iterations} decode iterations, ")
+
+
+def test_ssm_state_reserved_and_no_new_syncs():
+    """With SSM layers (reduced Granite: 4 Mamba-2 layers), ``engine.decode``
+    carries the bytes of recurrent state the cache holds for all slots, and
+    a replay makes as many host syncs as without: the uploads of the slots
+    the steps advance ride in its first and last."""
+    cfg = C.get_reduced("granite_4_0_h_micro")
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    trace = EngineTrace()
+    eng = ServingEngine(cfg, params, max_batch=3, max_len=64, trace=trace)
+    reqs = _reqs(5)
+    report = eng.run(reqs)
+    # a slot a Mamba layer: float32 state (8 heads x 16 x 16) and bf16
+    # conv windows (3 positions of 128 + 2 x 16 channels)
+    per_slot = 4 * (8 * 16 * 16 * 4 + 3 * (128 + 32) * 2)
+    decodes = [s for s in trace.spans if s.name == "engine.decode"]
+    assert len(decodes) == report.iterations > 0
+    assert {s.attrs["state_reserved"] for s in decodes} == {3 * per_slot}
+    lengths = {r["rid"]: len(r["prompt"]) for r in reqs}
+    for k, s in enumerate(trace.spans):
+        if s.name == "engine.prefill":
+            syncs = [c for c in trace.spans if c.parent == k]
+            assert len(syncs) == lengths[s.rid] + 2
 
 
 def test_evict_spans_count_preemptions(small):

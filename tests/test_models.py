@@ -79,7 +79,7 @@ def test_smoke_train_step(arch):
 @pytest.mark.parametrize("arch", ["internlm2_1_8b", "gemma3_12b",
                                   "mixtral_8x7b", "deepseek_v2_lite_16b",
                                   "mamba2_2_7b", "zamba2_7b",
-                                  "qwen1_5_32b"])
+                                  "qwen1_5_32b", "granite_4_0_h_micro"])
 def test_decode_matches_forward(arch):
     cfg = C.get_reduced(arch)
     params = init_params(RNG, cfg)
@@ -183,15 +183,17 @@ def _slot_forward(cfg, params, frames, toks):
 
 def _slot_axis(path):
     """The slot (batch) axis of a cache leaf: after the layer axis of the
-    scanned leaves, first in the prefix layers' leaves and ``len``."""
-    return 0 if path[0].key in ("len", "prefix") else 1
+    scanned leaves, first in the prefix layers' leaves, ``len`` and an SSM
+    cache's ``advance``."""
+    return 0 if path[0].key in ("len", "advance", "prefix") else 1
 
 
 def _assert_written_only(before, after):
     """Between two caches a step may change only what it writes: each
     slot's entry at its length (modulo a ring's size) in attention K/V
-    and MLA's latent and rope key, every SSM state; ``len`` advances by
-    one, cross-attention K/V stay as they were."""
+    and MLA's latent and rope key, every SSM state (every slot is
+    advanced here); ``len`` advances by one, cross-attention K/V and
+    ``advance`` stay as they were."""
     lens = before["len"]
 
     def check(path, old, new):
@@ -215,7 +217,8 @@ def _assert_written_only(before, after):
 @pytest.mark.parametrize("arch", ["internlm2_1_8b", "gemma3_12b",
                                   "mixtral_8x7b", "deepseek_v2_lite_16b",
                                   "mamba2_2_7b", "zamba2_7b",
-                                  "qwen1_5_32b", "seamless_m4t_large_v2"])
+                                  "qwen1_5_32b", "seamless_m4t_large_v2",
+                                  "granite_4_0_h_micro"])
 def test_donated_step_serves_slots_of_unequal_lengths(arch):
     """The engine's step (jitted, cache donated) over 3 slots that hold
     3, 14 and 36 tokens: each slot's logits are those of the same tokens
@@ -271,7 +274,8 @@ def test_donated_step_serves_slots_of_unequal_lengths(arch):
 def test_param_counts_match_ir():
     """The JAX model and the APEX IR agree on parameter counts."""
     from repro.models import param_count
-    for arch in ["internlm2_1_8b", "mixtral_8x7b", "mamba2_2_7b"]:
+    for arch in ["internlm2_1_8b", "mixtral_8x7b", "mamba2_2_7b",
+                 "granite_4_0_h_micro"]:
         cfg = C.get_reduced(arch)
         params = init_params(RNG, cfg)
         n_jax = param_count(params)
@@ -284,7 +288,8 @@ def test_full_config_ir_sizes():
     """Full assigned configs produce sane parameter counts (billions)."""
     expect = {"gemma3_12b": (10, 16), "qwen1_5_32b": (28, 36),
               "mixtral_8x7b": (40, 52), "mamba2_2_7b": (2.2, 3.2),
-              "deepseek_v2_lite_16b": (12, 18)}
+              "deepseek_v2_lite_16b": (12, 18),
+              "granite_4_0_h_micro": (3.1, 3.3)}
     for arch, (lo, hi) in expect.items():
         n = C.get_config(arch).to_ir().total_params() / 1e9
         assert lo <= n <= hi, f"{arch}: {n:.1f}B outside [{lo},{hi}]"

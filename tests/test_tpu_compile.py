@@ -11,6 +11,8 @@ chip's memory, and at batch 16 updates its donated cache in place: the
 output aliases the whole cache, and no layer of it is copied out.  The
 benchmark's deepseek-v2-lite-stage step at 32 x 2048 fits the chip and
 reads each MoE layer's expert stacks where they lie: none is copied out.
+Its granite-4.0-h-micro step at 32 x 2048 fits the chip and writes each
+Mamba layer's float32 state into the donated cache in place.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -170,11 +172,12 @@ def test_engine_step_updates_the_donated_cache_in_place(one_chip):
     assert not [o for o in outputs if o[1] == shape(k.shape[1:])]
 
 
-def _dsv2_stage():
-    """The benchmark's deepseek-v2-lite-stage: its program config and a
-    maker of its weights (``configs/deepseek-v2-lite-stage.py``)."""
-    path = CHIP / "configs" / "deepseek-v2-lite-stage.py"
-    spec = importlib.util.spec_from_file_location("dsv2_stage", path)
+def _bench_config(name):
+    """A configuration of the chip benchmark: its program config and a
+    maker of its weights (``configs/<name>.py``)."""
+    path = CHIP / "configs" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(name.replace("-", "_")
+                                                  .replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     raw = json.loads(path.with_suffix(".json").read_text())
@@ -187,7 +190,7 @@ def test_dsv2_step_reads_the_expert_stacks_in_place(one_chip):
     (64, 2048, 1408) or (64, 1408, 2048) expert stack: the expert scan
     reads each expert's block from the layer-stacked leaves where it lies,
     instead of copying the layer's stacks out first."""
-    cfg, make_params = _dsv2_stage()
+    cfg, make_params = _bench_config("deepseek-v2-lite-stage")
     compiled, _ = _engine_step(one_chip, 32, cfg, make_params)
     mem = compiled.memory_analysis()
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -202,3 +205,32 @@ def test_dsv2_step_reads_the_expert_stacks_in_place(one_chip):
     assert shape((R, E, d, f)) in {t for _, t in outputs}  # the parse sees it
     stacks = {shape((E, d, f)), shape((E, f, d))}
     assert not [o for o in outputs if o[1] in stacks]
+
+
+def test_granite_step_writes_its_state_in_place(one_chip):
+    """Granite-4.0-H-Micro's step at its cell's batch (32 slots of 2048)
+    fits the chip; the donated cache, 2.42 GB of it float32 SSM state and
+    conv windows, aliases the step's outputs whole, and no instruction
+    outside a fusion yields one layer's (32, 64, 64, 128) state: each Mamba
+    layer writes its slots' state into the layer-stacked leaf in place.
+    The Mamba decode's ops carry the ``ssm.decode`` scope."""
+    cfg, make_params = _bench_config("granite-4.0-h-micro")
+    compiled, cache = _engine_step(one_chip, 32, cfg, make_params)
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 9e9     # bf16 weights + cache
+    assert held < TPU_V5E.hbm_bytes, held
+    nbytes = lambda xs: sum(x.size * x.dtype.itemsize for x in xs)
+    state = [x for path, x in jax.tree_util.tree_leaves_with_path(cache)
+             if path[-1].key in ("ssm", "conv_x", "conv_bc")]
+    assert nbytes(state) > 2.4e9
+    assert mem.alias_size_in_bytes >= nbytes(jax.tree.leaves(cache)), mem
+    ssm = cache["blocks"]["l0"]["ssm"]
+    assert ssm.shape == (cfg.block_repeat, 32, 64, 64, 128)
+    shape = lambda dims: "f32[%s]" % ",".join(map(str, dims))
+    hlo = compiled.as_text()
+    outputs = _top_level_outputs(hlo)
+    assert shape(ssm.shape) in {t for _, t in outputs}   # the parse sees it
+    assert not [o for o in outputs if o[1] == shape(ssm.shape[1:])]
+    assert "/ssm.decode/" in hlo
